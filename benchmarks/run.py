@@ -58,6 +58,8 @@ import os
 import sys
 import traceback
 
+from repro.compat import enable_compilation_cache
+
 # bench name -> module under benchmarks/; imported lazily per bench so a
 # module that rots at import level fails alone instead of masking the rest
 SUITE = {
@@ -183,6 +185,7 @@ def main() -> None:
     if args.trace:
         from repro.obs import tracing
 
+    enable_compilation_cache()
     print("name,us_per_call,derived")
     failures = []
     for name in selected:

@@ -1,27 +1,49 @@
-"""Version-compat shims for the installed jax.
+"""The one seam between this repo and the installed jax (0.9.x).
 
-The repo targets current jax APIs but must run on older releases (this
-container ships 0.4.x): ``jax.shard_map`` and its ``check_vma`` kwarg landed
-after 0.4.x, where the same function lives under ``jax.experimental`` with a
-``check_rep`` kwarg.  Mesh axis-type compat lives in
+Every jax API whose name or home moves between releases is reached through
+this module, so a jax upgrade touches one file, and so is the one place the
+persistent compilation cache is configured.  Mesh construction lives in
 ``repro.launch.mesh.make_auto_mesh``.
 """
 
 from __future__ import annotations
 
+import os
+import pathlib
+
 import jax
 
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _SHARD_MAP_KW = {"check_rep": False}
+# fixed, inside the checkout: the cache directory is part of what makes a
+# later process find an entry, so a path built per run would never hit
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map with the replication/VMA check disabled, on any jax."""
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **_SHARD_MAP_KW
+    """``jax.shard_map`` with the varying-manual-axes check disabled."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
+
+
+def trace_state_clean() -> bool:
+    """True when no jax transformation (jit, vmap, grad, ...) is tracing.
+
+    Host-side state (caches, metrics, trace spans) is only touched at top
+    level, so tracing a caller never leaks tracers into it.
+    """
+    return jax.core.trace_ctx.is_top_level()
+
+
+def enable_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the directory (jax reads it
+    itself and nothing here overrides it); otherwise ``DEFAULT_CACHE_DIR``.
+    Every executable is cached, however fast it compiled.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path

@@ -11,8 +11,9 @@ tiles the m axis of a single sketch, except the tile here is a block of
 in a VMEM scratch accumulator for the entire item sweep.
 
 TPU has no random read-modify-write port, so the update is the same chunked
-one-hot compare-reduce as ``hll_fused``, widened to the block's flattened
-(row, bucket) cell space: an item owned by the current row block selects
+one-hot compare-reduce as ``hll_fused`` (``kernels.onehot``), widened to the
+block's flattened (row, bucket) cell space: an item owned by the current row
+block selects
 cell ``(key - block_start) * m + bucket``; items owned by other blocks (and
 padding) are neutralized by forcing their rank to 0, the identity of the
 bucket max.  Cost is O(items * row_block * m) VPU compares per row block —
@@ -28,9 +29,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANES = 128
+from repro.kernels.onehot import LANES, cell_rows, from_tiles, onehot_fold, to_tiles
+
 DEFAULT_BLOCK_ROWS = 8
-DEFAULT_CHUNK = 128
 # row_block * m VMEM-resident cells per grid step (the hll_fused m <= 4096
 # trade, applied to a block of sketches instead of one).
 MAX_BLOCK_CELLS = 1 << 12
@@ -46,15 +47,13 @@ def _bank_kernel(
     *,
     m: int,
     row_block: int,
-    block_rows: int,
-    chunk: int,
 ):
     jb = pl.program_id(0)  # bank row block
     step = pl.program_id(1)  # item tile
 
     @pl.when(step == 0)
     def _init():
-        scratch_ref[...] = regs_in_ref[...]
+        scratch_ref[...] = regs_in_ref[...].reshape(scratch_ref.shape)
 
     keys = keys_ref[...]  # (block_rows, LANES)
     local = keys - jb * row_block
@@ -64,30 +63,16 @@ def _bank_kernel(
     # aimed at cell 0.
     rank = jnp.where(owned, rank_ref[...], 0)
     col = jnp.where(owned, local * m + idx_ref[...], 0)
-
-    tile = block_rows * LANES
-    col_flat = col.reshape(tile)
-    rank_flat = rank.reshape(tile)
-    cell_ids = jax.lax.broadcasted_iota(jnp.int32, (chunk, row_block * m), 1)
-
-    def body(i, _):
-        cs = jax.lax.dynamic_slice(col_flat, (i * chunk,), (chunk,))
-        rs = jax.lax.dynamic_slice(rank_flat, (i * chunk,), (chunk,))
-        onehot = jnp.where(cs[:, None] == cell_ids, rs[:, None], 0)
-        contrib = jnp.max(onehot, axis=0, keepdims=True)  # (1, row_block*m)
-        scratch_ref[...] = jnp.maximum(scratch_ref[...], contrib)
-        return 0
-
-    jax.lax.fori_loop(0, tile // chunk, body, 0)
+    scratch_ref[...] = onehot_fold(scratch_ref[...], col, rank, jnp.max, jnp.maximum)
 
     @pl.when(step == pl.num_programs(1) - 1)
     def _flush():
-        out_ref[...] = scratch_ref[...]
+        out_ref[...] = scratch_ref[...].reshape(out_ref.shape)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("m", "row_block", "block_rows", "chunk", "interpret"),
+    static_argnames=("m", "row_block", "block_rows", "interpret"),
 )
 def bank_scatter_max(
     registers: jnp.ndarray,
@@ -98,7 +83,6 @@ def bank_scatter_max(
     m: int,
     row_block: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    chunk: int = DEFAULT_CHUNK,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Fold a precomputed (key, bucket, rank) stream into a (B, m) bank.
@@ -126,34 +110,28 @@ def bank_scatter_max(
         raise ValueError(f"stream tiles must be (rows, {LANES}), got {keys.shape}")
     if rows % block_rows != 0:
         raise ValueError(f"block_rows ({block_rows}) must divide rows ({rows})")
-    if (block_rows * LANES) % chunk != 0:
-        raise ValueError("chunk must divide the item tile size")
 
     row_blocks = bank_rows // row_block
     cells = row_block * m
-    # the (row_blocks, cells) layout keeps every reshape outside the kernel
-    regs2d = registers.reshape(row_blocks, cells)
+    crows = cell_rows(cells)
+    # the (row_blocks, cell_rows, 128) layout keeps every reshape of the
+    # stored bank outside the kernel
+    regs3d = to_tiles(registers.reshape(row_blocks, cells))
     grid = (row_blocks, rows // block_rows)
     stream_spec = pl.BlockSpec((block_rows, LANES), lambda j, i: (i, 0))
-    bank_spec = pl.BlockSpec((1, cells), lambda j, i: (j, 0))
+    bank_spec = pl.BlockSpec((None, crows, LANES), lambda j, i: (j, 0, 0))
     out = pl.pallas_call(
-        functools.partial(
-            _bank_kernel,
-            m=m,
-            row_block=row_block,
-            block_rows=block_rows,
-            chunk=chunk,
-        ),
+        functools.partial(_bank_kernel, m=m, row_block=row_block),
         grid=grid,
         in_specs=[stream_spec, stream_spec, stream_spec, bank_spec],
         out_specs=bank_spec,
-        out_shape=jax.ShapeDtypeStruct((row_blocks, cells), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((1, cells), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct(regs3d.shape, jnp.int32),
+        scratch_shapes=[pltpu.VMEM((1, crows * LANES), jnp.int32)],
         interpret=interpret,
     )(
         keys.astype(jnp.int32),
         idx.astype(jnp.int32),
         rank.astype(jnp.int32),
-        regs2d,
+        regs3d,
     )
-    return out.reshape(bank_rows, m)
+    return from_tiles(out, cells).reshape(bank_rows, m)

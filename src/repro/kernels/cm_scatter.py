@@ -1,13 +1,13 @@
 """Pallas TPU kernels: keyed scatter-ADD into a (B, d, w) count-min bank.
 
 The bank_scatter kernel folds a keyed HLL stream into a register bank with
-a chunked one-hot compare-reduce over the block's flattened cell space;
-this module is its additive mirror for the count-min family (DESIGN.md
-§13).  A count-min ingest lands d increments per item — one per depth row,
-at column ``r*w + idx_r`` of the row's flattened (d, w) counter slab — so
-the wrapper repeats each stream element d times and this kernel sums the
-resulting (key, cell, hit) stream into ``row_block`` whole counter slabs
-held VMEM-resident for the entire sweep.
+a chunked one-hot compare-reduce over the block's flattened cell space
+(``kernels.onehot``); this module is its additive mirror for the count-min
+family (DESIGN.md §13).  A count-min ingest lands d increments per item —
+one per depth row, at column ``r*w + idx_r`` of the row's flattened (d, w)
+counter slab — so the wrapper repeats each stream element d times and this
+kernel sums the resulting (key, cell, hit) stream into ``row_block`` whole
+counter slabs held VMEM-resident for the entire sweep.
 
 Where the max-lattice neutralizes padding with rank 0, the sum-lattice
 neutralizes it with hit 0 (the additive identity): padding and foreign
@@ -29,9 +29,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANES = 128
+from repro.kernels.onehot import LANES, cell_rows, from_tiles, onehot_fold, to_tiles
+
 DEFAULT_BLOCK_ROWS = 8
-DEFAULT_CHUNK = 128
 # row_block * d * w VMEM-resident cells per grid step (the bank_scatter
 # cap applied to count-min slabs: d=4, w=1024 fits exactly one row).
 MAX_BLOCK_CELLS = 1 << 12
@@ -47,15 +47,13 @@ def _cm_kernel(
     *,
     cells_per_row: int,
     row_block: int,
-    block_rows: int,
-    chunk: int,
 ):
     jb = pl.program_id(0)  # bank row block
     step = pl.program_id(1)  # item tile
 
     @pl.when(step == 0)
     def _init():
-        scratch_ref[...] = counters_in_ref[...]
+        scratch_ref[...] = counters_in_ref[...].reshape(scratch_ref.shape)
 
     keys = keys_ref[...]  # (block_rows, LANES)
     local = keys - jb * row_block
@@ -65,31 +63,16 @@ def _cm_kernel(
     # aimed at cell 0.
     val = jnp.where(owned, val_ref[...], 0)
     col = jnp.where(owned, local * cells_per_row + col_ref[...], 0)
-
-    tile = block_rows * LANES
-    col_flat = col.reshape(tile)
-    val_flat = val.reshape(tile)
-    cells = row_block * cells_per_row
-    cell_ids = jax.lax.broadcasted_iota(jnp.int32, (chunk, cells), 1)
-
-    def body(i, _):
-        cs = jax.lax.dynamic_slice(col_flat, (i * chunk,), (chunk,))
-        vs = jax.lax.dynamic_slice(val_flat, (i * chunk,), (chunk,))
-        onehot = jnp.where(cs[:, None] == cell_ids, vs[:, None], 0)
-        contrib = jnp.sum(onehot, axis=0, keepdims=True)  # (1, cells)
-        scratch_ref[...] = scratch_ref[...] + contrib
-        return 0
-
-    jax.lax.fori_loop(0, tile // chunk, body, 0)
+    scratch_ref[...] = onehot_fold(scratch_ref[...], col, val, jnp.sum, jnp.add)
 
     @pl.when(step == pl.num_programs(1) - 1)
     def _flush():
-        out_ref[...] = scratch_ref[...]
+        out_ref[...] = scratch_ref[...].reshape(out_ref.shape)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("cells_per_row", "row_block", "block_rows", "chunk", "interpret"),
+    static_argnames=("cells_per_row", "row_block", "block_rows", "interpret"),
 )
 def cm_scatter_add(
     counters: jnp.ndarray,
@@ -100,7 +83,6 @@ def cm_scatter_add(
     cells_per_row: int,
     row_block: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    chunk: int = DEFAULT_CHUNK,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Sum a precomputed (key, cell, hit) stream into a (B, d*w) bank.
@@ -131,37 +113,31 @@ def cm_scatter_add(
         raise ValueError(f"stream tiles must be (rows, {LANES}), got {keys.shape}")
     if rows % block_rows != 0:
         raise ValueError(f"block_rows ({block_rows}) must divide rows ({rows})")
-    if (block_rows * LANES) % chunk != 0:
-        raise ValueError("chunk must divide the item tile size")
 
     row_blocks = bank_rows // row_block
     cells = row_block * cells_per_row
-    # the (row_blocks, cells) layout keeps every reshape outside the kernel
-    cnt2d = counters.reshape(row_blocks, cells)
+    crows = cell_rows(cells)
+    # the (row_blocks, cell_rows, 128) layout keeps every reshape of the
+    # stored bank outside the kernel
+    cnt3d = to_tiles(counters.reshape(row_blocks, cells))
     grid = (row_blocks, rows // block_rows)
     stream_spec = pl.BlockSpec((block_rows, LANES), lambda j, i: (i, 0))
-    bank_spec = pl.BlockSpec((1, cells), lambda j, i: (j, 0))
+    bank_spec = pl.BlockSpec((None, crows, LANES), lambda j, i: (j, 0, 0))
     out = pl.pallas_call(
-        functools.partial(
-            _cm_kernel,
-            cells_per_row=cells_per_row,
-            row_block=row_block,
-            block_rows=block_rows,
-            chunk=chunk,
-        ),
+        functools.partial(_cm_kernel, cells_per_row=cells_per_row, row_block=row_block),
         grid=grid,
         in_specs=[stream_spec, stream_spec, stream_spec, bank_spec],
         out_specs=bank_spec,
-        out_shape=jax.ShapeDtypeStruct((row_blocks, cells), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((1, cells), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct(cnt3d.shape, jnp.int32),
+        scratch_shapes=[pltpu.VMEM((1, crows * LANES), jnp.int32)],
         interpret=interpret,
     )(
         keys.astype(jnp.int32),
         col.astype(jnp.int32),
         val.astype(jnp.int32),
-        cnt2d,
+        cnt3d,
     )
-    return out.reshape(bank_rows, cells_per_row)
+    return from_tiles(out, cells).reshape(bank_rows, cells_per_row)
 
 
 def _cm_fold_kernel(mask_ref, ring_ref, out_ref, scratch_ref):
@@ -172,7 +148,7 @@ def _cm_fold_kernel(mask_ref, ring_ref, out_ref, scratch_ref):
         scratch_ref[...] = jnp.zeros_like(scratch_ref)
 
     # masked slices fold as 0, the identity of the cell sum
-    contrib = jnp.where(mask_ref[...] > 0, ring_ref[0], 0)
+    contrib = jnp.where(mask_ref[w] > 0, ring_ref[...], 0)
     scratch_ref[...] = scratch_ref[...] + contrib
 
     @pl.when(w == pl.num_programs(1) - 1)
@@ -217,18 +193,19 @@ def cm_window_fold_sum(
 
     row_blocks = bank_rows // row_block
     cells = row_block * cells_per_row
-    ring3d = ring.reshape(window, row_blocks, cells)
+    crows = cell_rows(cells)
+    ring4d = to_tiles(ring.reshape(window, row_blocks, cells))
     grid = (row_blocks, window)
     out = pl.pallas_call(
         _cm_fold_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda j, w: (w, 0)),
-            pl.BlockSpec((1, 1, cells), lambda j, w: (w, j, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # (W,) mask, whole
+            pl.BlockSpec((None, None, crows, LANES), lambda j, w: (w, j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, cells), lambda j, w: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((row_blocks, cells), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((1, cells), jnp.int32)],
+        out_specs=pl.BlockSpec((None, crows, LANES), lambda j, w: (j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(ring4d.shape[1:], jnp.int32),
+        scratch_shapes=[pltpu.VMEM((crows, LANES), jnp.int32)],
         interpret=interpret,
-    )(mask.astype(jnp.int32).reshape(window, 1), ring3d)
-    return out.reshape(bank_rows, cells_per_row)
+    )(mask.astype(jnp.int32), ring4d)
+    return from_tiles(out, cells).reshape(bank_rows, cells_per_row)

@@ -8,13 +8,14 @@ sweep — input words stream HBM->VMEM once, hashes/ranks/updates never touch
 HBM, and the registers are written back exactly once at the end.
 
 TPU has no random read-modify-write port, so the bucket update is expressed
-as a chunked one-hot compare-reduce: a (chunk, m) equality mask against the
-bucket iota selects each item's rank into its bucket column and a max over
-the chunk axis merges the chunk — "updates to the same counter arriving
-during the read-modify-write cycle are merged" (paper §V-A.4), except here
-the merge window is the whole chunk.  Cost is O(items * m) VPU compares,
-which is the right trade only for small m; for p=16 the scatter-based path
-in sketch/hll.py is used instead (see DESIGN.md §2).
+as a chunked one-hot compare-reduce (``kernels.onehot``): a (128, m)
+equality mask against the bucket iota selects each item's rank into its
+bucket column and a max over the chunk axis merges the chunk — "updates to
+the same counter arriving during the read-modify-write cycle are merged"
+(paper §V-A.4), except here the merge window is the whole chunk.  Cost is
+O(items * m) VPU compares, which is the right trade only for small m; for
+p=16 the scatter-based path in sketch/hll.py is used instead (see DESIGN.md
+§2).
 
 Padding items are neutralized by forcing their rank to 0: registers are
 non-negative and max(r, 0) is the identity, so a rank-0 update is a no-op
@@ -30,12 +31,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.onehot import LANES, onehot_fold
 from repro.sketch import hll
 from repro.sketch.hll import HLLConfig
 
-LANES = 128
 DEFAULT_BLOCK_ROWS = 8
-DEFAULT_CHUNK = 128
 MAX_FUSED_P = 12
 
 
@@ -48,7 +48,6 @@ def _fused_kernel(
     *,
     cfg: HLLConfig,
     block_rows: int,
-    chunk: int,
 ):
     step = pl.program_id(0)
 
@@ -66,28 +65,14 @@ def _fused_kernel(
     pos = pos + step * tile
     rank = jnp.where(pos < n_valid_ref[0, 0], rank, 0)
 
-    idx_flat = idx.reshape(tile)
-    rank_flat = rank.reshape(tile)
-    bucket_ids = jax.lax.broadcasted_iota(jnp.int32, (chunk, cfg.m), 1)
-
-    def body(i, _):
-        ids = jax.lax.dynamic_slice(idx_flat, (i * chunk,), (chunk,))
-        rks = jax.lax.dynamic_slice(rank_flat, (i * chunk,), (chunk,))
-        onehot = jnp.where(ids[:, None] == bucket_ids, rks[:, None], 0)
-        contrib = jnp.max(onehot, axis=0, keepdims=True)  # (1, m)
-        scratch_ref[...] = jnp.maximum(scratch_ref[...], contrib)
-        return 0
-
-    jax.lax.fori_loop(0, tile // chunk, body, 0)
+    scratch_ref[...] = onehot_fold(scratch_ref[...], idx, rank, jnp.max, jnp.maximum)
 
     @pl.when(step == pl.num_programs(0) - 1)
     def _flush():
         out_ref[...] = scratch_ref[...]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("cfg", "block_rows", "chunk", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("cfg", "block_rows", "interpret"))
 def hll_update_fused(
     registers: jnp.ndarray,
     items: jnp.ndarray,
@@ -95,7 +80,6 @@ def hll_update_fused(
     cfg: HLLConfig,
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    chunk: int = DEFAULT_CHUNK,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Aggregate (rows, 128) items into (1, m) int32 registers, fully fused.
@@ -114,17 +98,13 @@ def hll_update_fused(
     rows = items.shape[0]
     if rows % block_rows != 0:
         raise ValueError(f"rows ({rows}) must divide block_rows ({block_rows})")
-    if (block_rows * LANES) % chunk != 0:
-        raise ValueError("tile size must divide chunk")
     if registers.shape != (1, cfg.m):
         raise ValueError(f"registers must be (1, {cfg.m}), got {registers.shape}")
 
     grid = (rows // block_rows,)
     full_regs = pl.BlockSpec((1, cfg.m), lambda i: (0, 0))
     return pl.pallas_call(
-        functools.partial(
-            _fused_kernel, cfg=cfg, block_rows=block_rows, chunk=chunk
-        ),
+        functools.partial(_fused_kernel, cfg=cfg, block_rows=block_rows),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0)),  # n_valid

@@ -7,17 +7,17 @@ compacts under pressure; this kernel is the compaction's scatter phase.  The
 plus the newly hashed append buffer — sweeps a grid tiled over *bank row
 blocks*, exactly like ``bank_scatter`` tiles ingest, but the VMEM-resident
 tile here is the row block's bucket -> max-rank pair map (dense-addressed so
-the TPU's chunked one-hot compare-reduce can stand in for the random
-read-modify-write port it does not have), initialized to zero instead of
-carrying registers in.
+the TPU's chunked one-hot compare-reduce, ``kernels.onehot``, can stand in
+for the random read-modify-write port it does not have), initialized to zero
+instead of carrying registers in.
 
-At the final item tile the kernel flushes two outputs per row block: the
-deduped pair tile itself (``row_block * m`` int32 cells; the host-side COO
-compaction reads the surviving ``(bucket, max rank)`` pairs back out of it in
-bucket order) and the per-row distinct-bucket counts (one in-VMEM popcount
-over the tile), which is everything promotion detection needs — no second
-pass over the stream.  Cost is O(items * row_block * m) VPU compares per row
-block: the small-m trade again, so the cap mirrors ``MAX_BLOCK_CELLS``.
+At the final item tile the kernel flushes the deduped pair tile
+(``row_block * m`` int32 cells; the host-side COO compaction reads the
+surviving ``(bucket, max rank)`` pairs back out of it in bucket order); the
+per-row distinct-bucket counts promotion detection needs are one popcount
+over that map in the same jitted call — no second pass over the stream.
+Cost is O(items * row_block * m) VPU compares per row block: the small-m
+trade again, so the cap mirrors ``MAX_BLOCK_CELLS``.
 """
 
 from __future__ import annotations
@@ -29,9 +29,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANES = 128
+from repro.kernels.onehot import LANES, cell_rows, from_tiles, onehot_fold
+
 DEFAULT_BLOCK_ROWS = 8
-DEFAULT_CHUNK = 128
 # row_block * m VMEM-resident pair cells per grid step (same budget as the
 # bank_scatter accumulator).
 MAX_BLOCK_CELLS = 1 << 12
@@ -42,13 +42,10 @@ def _sparse_kernel(
     idx_ref,
     rank_ref,
     pairs_ref,
-    count_ref,
     scratch_ref,
     *,
     m: int,
     row_block: int,
-    block_rows: int,
-    chunk: int,
 ):
     jb = pl.program_id(0)  # bank row block
     step = pl.program_id(1)  # item tile
@@ -67,34 +64,16 @@ def _sparse_kernel(
     # aimed at cell 0.
     rank = jnp.where(owned, rank_ref[...], 0)
     col = jnp.where(owned, local * m + idx_ref[...], 0)
-
-    tile = block_rows * LANES
-    col_flat = col.reshape(tile)
-    rank_flat = rank.reshape(tile)
-    cell_ids = jax.lax.broadcasted_iota(jnp.int32, (chunk, row_block * m), 1)
-
-    def body(i, _):
-        cs = jax.lax.dynamic_slice(col_flat, (i * chunk,), (chunk,))
-        rs = jax.lax.dynamic_slice(rank_flat, (i * chunk,), (chunk,))
-        onehot = jnp.where(cs[:, None] == cell_ids, rs[:, None], 0)
-        contrib = jnp.max(onehot, axis=0, keepdims=True)  # (1, row_block*m)
-        scratch_ref[...] = jnp.maximum(scratch_ref[...], contrib)
-        return 0
-
-    jax.lax.fori_loop(0, tile // chunk, body, 0)
+    scratch_ref[...] = onehot_fold(scratch_ref[...], col, rank, jnp.max, jnp.maximum)
 
     @pl.when(step == pl.num_programs(1) - 1)
     def _flush():
-        pairs_ref[...] = scratch_ref[...]
-        tile2d = scratch_ref[...].reshape(row_block, m)
-        count_ref[...] = jnp.sum(
-            (tile2d > 0).astype(jnp.int32), axis=1
-        ).reshape(1, row_block)
+        pairs_ref[...] = scratch_ref[...].reshape(pairs_ref.shape)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("rows", "m", "row_block", "block_rows", "chunk", "interpret"),
+    static_argnames=("rows", "m", "row_block", "block_rows", "interpret"),
 )
 def sparse_scatter_coo(
     keys: jnp.ndarray,
@@ -105,7 +84,6 @@ def sparse_scatter_coo(
     m: int,
     row_block: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    chunk: int = DEFAULT_CHUNK,
     interpret: bool = False,
 ) -> tuple:
     """Dedup a routed (key, bucket, rank) stream into per-row pair maps.
@@ -135,36 +113,26 @@ def sparse_scatter_coo(
         raise ValueError(
             f"block_rows ({block_rows}) must divide tile rows ({tile_rows})"
         )
-    if (block_rows * LANES) % chunk != 0:
-        raise ValueError("chunk must divide the item tile size")
 
     row_blocks = rows // row_block
     cells = row_block * m
+    crows = cell_rows(cells)
     grid = (row_blocks, tile_rows // block_rows)
     stream_spec = pl.BlockSpec((block_rows, LANES), lambda j, i: (i, 0))
-    pairs, counts = pl.pallas_call(
-        functools.partial(
-            _sparse_kernel,
-            m=m,
-            row_block=row_block,
-            block_rows=block_rows,
-            chunk=chunk,
-        ),
+    pairs = pl.pallas_call(
+        functools.partial(_sparse_kernel, m=m, row_block=row_block),
         grid=grid,
         in_specs=[stream_spec, stream_spec, stream_spec],
-        out_specs=[
-            pl.BlockSpec((1, cells), lambda j, i: (j, 0)),
-            pl.BlockSpec((1, row_block), lambda j, i: (j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((row_blocks, cells), jnp.int32),
-            jax.ShapeDtypeStruct((row_blocks, row_block), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((1, cells), jnp.int32)],
+        out_specs=pl.BlockSpec((None, crows, LANES), lambda j, i: (j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((row_blocks, crows, LANES), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((1, crows * LANES), jnp.int32)],
         interpret=interpret,
     )(
         keys.astype(jnp.int32),
         idx.astype(jnp.int32),
         rank.astype(jnp.int32),
     )
-    return pairs.reshape(rows, m), counts.reshape(rows)
+    pairs = from_tiles(pairs, cells).reshape(rows, m)
+    # one popcount over the deduped map (m lanes per row do not tile the
+    # kernel's 128-lane cell rows, so it runs as one XLA reduce)
+    return pairs, jnp.sum(pairs > 0, axis=1, dtype=jnp.int32)

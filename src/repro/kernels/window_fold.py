@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANES = 128
+from repro.kernels.onehot import LANES, cell_rows, from_tiles, to_tiles
+
 # row_block * m VMEM-resident cells per grid step (the bank_scatter cap,
 # applied to the fold side of the window).
 MAX_BLOCK_CELLS = 1 << 12
@@ -39,7 +40,7 @@ def _window_kernel(mask_ref, ring_ref, out_ref, scratch_ref):
         scratch_ref[...] = jnp.zeros_like(scratch_ref)
 
     # masked slices fold as 0, the identity of the bucket max
-    contrib = jnp.where(mask_ref[...] > 0, ring_ref[0], 0)
+    contrib = jnp.where(mask_ref[w] > 0, ring_ref[...], 0)
     scratch_ref[...] = jnp.maximum(scratch_ref[...], contrib)
 
     @pl.when(w == pl.num_programs(1) - 1)
@@ -80,22 +81,24 @@ def window_fold_max(
 
     row_blocks = bank_rows // row_block
     cells = row_block * m
-    # the (W, row_blocks, cells) layout keeps every reshape outside the kernel
-    ring3d = ring.reshape(window, row_blocks, cells)
+    crows = cell_rows(cells)
+    # the (W, row_blocks, cell_rows, 128) layout keeps every reshape
+    # outside the kernel
+    ring4d = to_tiles(ring.reshape(window, row_blocks, cells))
     grid = (row_blocks, window)
     out = pl.pallas_call(
         _window_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda j, w: (w, 0)),
-            pl.BlockSpec((1, 1, cells), lambda j, w: (w, j, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # (W,) mask, whole
+            pl.BlockSpec((None, None, crows, LANES), lambda j, w: (w, j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, cells), lambda j, w: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((row_blocks, cells), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((1, cells), jnp.int32)],
+        out_specs=pl.BlockSpec((None, crows, LANES), lambda j, w: (j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(ring4d.shape[1:], jnp.int32),
+        scratch_shapes=[pltpu.VMEM((crows, LANES), jnp.int32)],
         interpret=interpret,
-    )(mask.astype(jnp.int32).reshape(window, 1), ring3d)
-    return out.reshape(bank_rows, m)
+    )(mask.astype(jnp.int32), ring4d)
+    return from_tiles(out, cells).reshape(bank_rows, m)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "row_block", "interpret"))

@@ -15,13 +15,11 @@ import jax
 
 
 def make_auto_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where the installed jax
-    supports them (jax.sharding.AxisType landed after 0.4.x; on older
-    releases every axis is Auto already, so the kwarg is simply dropped)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """jax.make_mesh with every axis explicitly Auto (sharding propagated
+    by the compiler, as the shard_map-based serve path expects)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False, tp: int = 16):

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 import numpy as np
 
+from repro.compat import enable_compilation_cache
 from repro.configs import ARCH_IDS, get_arch
 from repro.obs import metrics, tracing
 from repro.obs.format import (
@@ -109,6 +110,7 @@ def main():
     ap.add_argument("--reduced", action="store_true", default=True)
     ap.add_argument("--full-config", dest="reduced", action="store_false")
     args = ap.parse_args()
+    enable_compilation_cache()
 
     if args.metrics_out:
         metrics.enable()

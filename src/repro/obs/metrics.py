@@ -15,7 +15,8 @@ safe to leave compiled into every hot seam:
   a single attribute load + function call (gated ≤3% median on
   ``SketchBank.update_many`` by ``benchmarks/bench_obs.py``).
 * **Trace hygiene.**  No record site runs under an active jax trace:
-  :func:`recording` reuses the PR-8 gate (``jax.core.trace_state_clean()``,
+  :func:`recording` reuses the window cache's gate
+  (``repro.compat.trace_state_clean()``,
   the same check ``WindowedBank._concrete`` makes before touching hidden
   host state).  Tracing a jitted caller therefore neither leaks tracers
   into the registry nor double-books work the compiled executable replays
@@ -32,7 +33,7 @@ import threading
 import time
 from typing import Callable, Dict, Optional
 
-import jax
+from repro.compat import trace_state_clean
 
 __all__ = [
     "enable",
@@ -164,7 +165,7 @@ def recording() -> bool:
     path never pays the jax call; under an active trace the site is
     skipped entirely (trace hygiene, DESIGN.md §15).
     """
-    return _ENABLED and jax.core.trace_state_clean()
+    return _ENABLED and trace_state_clean()
 
 
 def reset() -> None:
@@ -282,7 +283,7 @@ def seam(axis: str, backend: str) -> "_Timer":
     live_t = _trace_active()
     if not (live_m or live_t):
         return _NULL
-    if not jax.core.trace_state_clean():
+    if not trace_state_clean():
         return _NULL
     key = f"dispatch.{axis}.{backend}"
     return _Timer(

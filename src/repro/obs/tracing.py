@@ -23,8 +23,7 @@ import threading
 import time
 from typing import Optional
 
-import jax
-
+from repro.compat import trace_state_clean
 from repro.obs import metrics as _metrics
 
 __all__ = [
@@ -65,7 +64,7 @@ def active() -> bool:
 
 
 def _emit(name: str, t0: float, dur_s: float, args: Optional[dict] = None):
-    if not _ACTIVE or not jax.core.trace_state_clean():
+    if not _ACTIVE or not trace_state_clean():
         return
     event = {
         "name": name,

@@ -28,6 +28,7 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.obs import metrics as obs_metrics
 
@@ -47,14 +48,15 @@ class DoubleBuffer:
     def depth(self) -> int:
         return len(self._slots)
 
-    def stage(self, *host_arrays) -> Tuple[jax.Array, ...]:
+    def stage(self, *host_arrays, sharding=None) -> Tuple[jax.Array, ...]:
         """Async-transfer ``host_arrays``; returns the device handles.
 
-        Rotates through the slot ring, so the previous tick's buffers
-        stay pinned while its scatter is still in flight and the slot
-        being overwritten is always the oldest (already-retired) one.
+        ``sharding`` places them (None: the default device).  Rotates
+        through the slot ring, so the previous tick's buffers stay pinned
+        while its scatter is still in flight and the slot being
+        overwritten is always the oldest (already-retired) one.
         """
-        staged = tuple(jax.device_put(a) for a in host_arrays)
+        staged = tuple(jax.device_put(a, sharding) for a in host_arrays)
         self._slots[self._tick % len(self._slots)] = staged
         self._tick += 1
         return staged
@@ -94,11 +96,12 @@ class CoalescingQueue:
     def pending_items(self) -> int:
         return sum(k.shape[0] for k, _ in self._chunks)
 
-    def drain(self, stage: bool = True) -> Optional[Tuple]:
+    def drain(self, stage: bool = True, sharding=None) -> Optional[Tuple]:
         """Pop everything pending as ONE merged (keys, items) batch.
 
-        ``stage=True`` routes the merge through the double buffer and
-        returns device handles (the fused-scatter path); ``stage=False``
+        ``stage=True`` routes the merge through the double buffer (placed
+        by ``sharding``) and returns device handles (the fused-scatter
+        path); ``stage=False``
         returns the host arrays for host-orchestrated carriers.  An
         empty queue returns None — a tick with no traffic must not
         dispatch anything.
@@ -113,16 +116,21 @@ class CoalescingQueue:
         obs_metrics.observe("serve.coalesce.batches_per_tick", len(chunks))
         obs_metrics.observe("serve.coalesce.batch_items", keys.shape[0])
         if stage:
-            return self._staging.stage(keys, items)
+            return self._staging.stage(keys, items, sharding=sharding)
         return keys, items
 
     def flush_into(self, bank, plan=None):
         """Drain into ``bank`` with ONE ``update_many``; returns the new
         bank (unchanged when nothing is pending).  Device-stages unless
         the carrier ingests on host (a ``pending_pairs`` surface marks
-        the HybridBank append-buffer family)."""
+        the HybridBank append-buffer family); a sharded ``plan`` stages
+        the batch replicated over its mesh, where every row block reads
+        the whole stream, instead of on one device."""
         host_carrier = hasattr(bank, "pending_pairs")
-        merged = self.drain(stage=not host_carrier)
+        sharding = None
+        if plan is not None and plan.placement == "sharded":
+            sharding = NamedSharding(plan.mesh, PartitionSpec())
+        merged = self.drain(stage=not host_carrier, sharding=sharding)
         if merged is None:
             return bank
         return bank.update_many(merged[0], merged[1], plan)

@@ -14,12 +14,15 @@ This module also owns the tiling/padding wrappers that used to live in
 always padded, never rejected: padded positions get rank 0, and a rank-0
 update is the identity of the bucket max.
 
-``interpret`` defaults to True off-TPU (this container) and False on TPU,
-where the Mosaic-compiled kernel runs.
+``interpret`` defaults to True off-TPU and False on TPU, where the
+Mosaic-compiled kernel runs; ``PALLAS_MODES`` tallies how every wrapper call
+resolved it, so an on-chip check can prove no kernel fell back to the
+interpreter.
 """
 
 from __future__ import annotations
 
+import collections
 from functools import partial
 from typing import Optional, Tuple
 
@@ -72,8 +75,17 @@ def _window_kernel_module():
     return _window
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+# "compiled" / "interpret" -> how many Pallas wrapper calls resolved to it
+PALLAS_MODES: collections.Counter = collections.Counter()
+
+
+def _resolve_interpret(interpret: Optional[bool]) -> bool:
+    """A plan's ``interpret`` flag: None = compiled on TPU, interpreted
+    elsewhere.  Every resolution is tallied in ``PALLAS_MODES``."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    PALLAS_MODES["interpret" if interpret else "compiled"] += 1
+    return interpret
 
 
 def _pad_to_tiles(flat: jnp.ndarray, tile_items: int) -> Tuple[jnp.ndarray, int]:
@@ -147,7 +159,7 @@ def hash_rank(
     """Fused murmur3+rank of a flat item stream -> (idx, rank) int32 arrays."""
     _hash, _, _ = _kernels()
     block_rows = _hash.DEFAULT_BLOCK_ROWS if block_rows is None else block_rows
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     flat = items.reshape(-1)
     tiled, n = _pad_to_tiles(flat, block_rows * LANES)
     idx, rank = _hash.hash_rank(
@@ -165,7 +177,7 @@ def bucket_fold(
     """Fold (k, m) partial registers (any int dtype) -> (m,) by max."""
     _, _fold, _ = _kernels()
     block_m = _fold.DEFAULT_BLOCK_M if block_m is None else block_m
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     out = _fold.bucket_fold(
         partials.astype(jnp.int32), block_m=block_m, interpret=interpret
     )
@@ -187,7 +199,7 @@ def hll_update(
     """
     _, _, _fused = _kernels()
     block_rows = _fused.DEFAULT_BLOCK_ROWS if block_rows is None else block_rows
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     flat = items.reshape(-1)
     tiled, n = _pad_to_tiles(flat, block_rows * LANES)
     n_valid = jnp.full((1, 1), n, jnp.int32)
@@ -212,7 +224,7 @@ def pipelined_update(
     the fused kernel, folds partials with the bucket_fold kernel, and merges
     into the running registers.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     flat = items.reshape(-1)
     n = flat.shape[0]
     per = -(-n // pipelines)
@@ -315,7 +327,7 @@ def bank_update(
     """
     _bank = _bank_kernel_module()
     _hash, _, _ = _kernels()
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     bank_rows, m = registers.shape
     if m > _bank.MAX_BLOCK_CELLS:
         raise ValueError(
@@ -427,7 +439,7 @@ def window_fold(
     the VMEM cell cap.
     """
     _window = _window_kernel_module()
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     window, bank_rows, m = ring.shape
     if m > _window.MAX_BLOCK_CELLS:
         raise ValueError(
@@ -501,7 +513,7 @@ def window_merge(
     scratch accumulator.
     """
     _window = _window_kernel_module()
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     _, bank_rows, m = parts.shape
     if m > _window.MAX_BLOCK_CELLS:
         raise ValueError(
@@ -632,7 +644,7 @@ def sparse_merge(
     trade); the default row_block is the widest under the VMEM cell cap.
     """
     _sparse = _sparse_kernel_module()
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     m = cfg.m
     if m > _sparse.MAX_BLOCK_CELLS:
         raise ValueError(
@@ -810,7 +822,7 @@ def cm_update(
     """
     _cms = _cm_kernel_module()
     _cm = _cm_module()
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     rows, depth, width = counters.shape
     cells = depth * width
     if cells > _cms.MAX_BLOCK_CELLS:
@@ -883,7 +895,7 @@ def cm_window_fold(
     2^32).  Small-slab banks only (d*w under the VMEM cell cap).
     """
     _cms = _cm_kernel_module()
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     window, rows, depth, width = ring.shape
     cells = depth * width
     if cells > _cms.MAX_BLOCK_CELLS:

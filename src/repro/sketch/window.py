@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compat import trace_state_clean
 from repro.obs import metrics as obs_metrics
 from repro.sketch import hll
 from repro.sketch.bank import SketchBank, _sharded_estimate_fn
@@ -334,7 +335,7 @@ class WindowedBank(_RingReads):
         active trace, so any derived value (``self.epoch``, a fold) would
         still come back abstract.
         """
-        return jax.core.trace_state_clean() and not any(
+        return trace_state_clean() and not any(
             isinstance(leaf, jax.core.Tracer)
             for leaf in (self.registers, self.n_items, self.cursor, self.epochs)
         )
@@ -855,7 +856,7 @@ class HybridWindowedBank(_RingReads):
         last_k = self._check_last_k(last_k)
         # under an active trace the merge ops would come back abstract;
         # caching them would leak dead tracers into later eager reads
-        cacheable = jax.core.trace_state_clean()
+        cacheable = trace_state_clean()
         if cacheable:
             cache = self.__dict__.setdefault("_fold_cache", {})
             hit = cache.get(last_k)
@@ -1243,7 +1244,7 @@ class MultiResWindowedBank:
         backend = get_window_backend(plan.backend)
         # same trace-state rule as the dense ring's cache: never memoize
         # values minted under someone else's jit trace
-        cacheable = jax.core.trace_state_clean()
+        cacheable = trace_state_clean()
         if cacheable:
             cache = self.__dict__.setdefault("_fold_cache", {})
             key = (last_k, plan.backend, plan.pipelines, plan.placement)

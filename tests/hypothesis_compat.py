@@ -3,9 +3,9 @@
 ``hypothesis`` is a dev-only dependency (requirements-dev.txt).  When it is
 missing, the deterministic tests in a module must still collect and run, so
 this module degrades gracefully: ``@given(...)`` turns the property test
-into a skip, ``@settings(...)`` becomes a no-op, and ``st.<anything>(...)``
-returns inert placeholders that are only ever passed to the stubbed
-``given``.
+into a skip, ``@settings(...)`` and ``@example(...)`` become no-ops, and
+``st.<anything>(...)`` returns inert placeholders that are only ever passed
+to the stubbed ``given``.
 
 When hypothesis IS present, importing this module registers the repo's
 settings profiles (all with the deadline off — JAX dispatch latency is too
@@ -22,7 +22,13 @@ reproduce from the seed alone):
 import os
 
 try:
-    from hypothesis import HealthCheck, given, settings, strategies as st  # noqa: F401
+    from hypothesis import (  # noqa: F401
+        HealthCheck,
+        example,
+        given,
+        settings,
+        strategies as st,
+    )
 
     HAVE_HYPOTHESIS = True
 
@@ -62,6 +68,8 @@ except ImportError:  # pragma: no cover - exercised only without hypothesis
             return fn
 
         return deco
+
+    example = settings
 
     def given(*args, **kwargs):
         def deco(fn):

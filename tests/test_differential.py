@@ -32,7 +32,7 @@ from repro.sketch import (
     available_estimators,
     available_window_backends,
 )
-from tests.hypothesis_compat import HAVE_HYPOTHESIS, given, st
+from tests.hypothesis_compat import HAVE_HYPOTHESIS, example, given, st
 from tests.reference_model import (
     CounterReferenceModel,
     CountMinSUT,
@@ -351,6 +351,7 @@ else:  # pragma: no cover - placeholder consumed by the stubbed @given
 # (ci/nightly/dev — tests/hypothesis_compat.py), so the nightly schedule
 # actually deepens this sweep
 @given(seeds=op_seeds, windowed=st.booleans())
+@example(seeds=[0], windowed=True)
 def test_hypothesis_ops_hybrid_matches_dense_and_oracle(seeds, windowed):
     """Generated sequences: hybrid == dense bit-for-bit, both in-band."""
     window = 3

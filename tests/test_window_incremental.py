@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from hypothesis_compat import given, st
+from hypothesis_compat import example, given, st
 
 from repro.sketch import (
     CMConfig,
@@ -134,6 +134,7 @@ def test_random_walk_reads_bit_identical(backend):
 
 
 @given(ops=st.lists(st.integers(min_value=0, max_value=9), max_size=24))
+@example(ops=[0])
 def test_random_walk_reads_bit_identical_property(ops):
     plan = ExecutionPlan(backend="jnp")
     win = WindowedBank.empty(4, 5, CFG)
