@@ -7,9 +7,9 @@ budget makes that a gate, not a hope: this bench times
 ``SketchBank.update_many`` with the shipped (disabled) instrumentation
 against a passthrough baseline — the seam wrappers swapped back to the
 raw backends and the call-site record fns no-op'd — and asserts the
-median overhead stays within ``OVERHEAD_GATE`` (3%).  Enabled-mode and
-trace-capture costs are measured and reported unasserted: they are paid
-only by runs that asked for them.
+median overhead stays within ``OVERHEAD_GATE`` (3%).  The enabled-mode
+cost is measured and reported unasserted: it is paid only by runs that
+asked for it.
 
 Writes ``BENCH_obs.json`` so the overhead trajectory is tracked like
 every other bench (smoke runs write the gitignored ``.smoke.json``
@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit, time_fn, write_bench_json
-from repro.obs import metrics, tracing
+from repro.obs import metrics
 from repro.sketch import HLLConfig, SketchBank
 from repro.sketch import plan as planlib
 
@@ -81,10 +81,6 @@ def _median_s(rows: int, n: int, iters: int) -> float:
 
 def run(full: bool = False, smoke: bool = False):
     assert not metrics.enabled(), "bench_obs must start with metrics off"
-    if tracing.active():
-        # a run.py --trace capture would put the seam path back on the
-        # "disabled" arm; the gate measures the shipped default instead
-        tracing.stop_trace()
     rows, n = (16, 1024) if smoke else (64, 4096)
     iters = 7 if smoke else 15
     rounds = 3 if smoke else 5
@@ -99,15 +95,12 @@ def run(full: bool = False, smoke: bool = False):
     disabled_s, baseline_s = min(disabled), min(baseline)
     ratio = disabled_s / baseline_s
 
-    # enabled-mode + live-trace costs: reported, not gated — only runs
-    # that asked for metrics/tracing pay them
+    # enabled-mode cost: reported, not gated — only runs that asked for
+    # metrics pay it
     metrics.enable()
     enabled_s = _median_s(rows, n, iters)
     metrics.disable()
     metrics.reset()
-    tracing.start_trace()
-    traced_s = _median_s(rows, n, iters)
-    tracing.stop_trace()
 
     emit(
         "obs_overhead_disabled",
@@ -120,11 +113,6 @@ def run(full: bool = False, smoke: bool = False):
         enabled_s * 1e6,
         f"ratio={enabled_s / baseline_s:.3f}x (unasserted)",
     )
-    emit(
-        "obs_overhead_traced",
-        traced_s * 1e6,
-        f"ratio={traced_s / baseline_s:.3f}x (unasserted)",
-    )
 
     out = {
         "B": rows,
@@ -134,8 +122,6 @@ def run(full: bool = False, smoke: bool = False):
         "disabled_over_baseline": ratio,
         "enabled_us": enabled_s * 1e6,
         "enabled_over_baseline": enabled_s / baseline_s,
-        "traced_us": traced_s * 1e6,
-        "traced_over_baseline": traced_s / baseline_s,
         "gate": OVERHEAD_GATE,
         "smoke": smoke,
     }

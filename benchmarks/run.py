@@ -36,12 +36,10 @@ jumps can be told apart from runner swaps; ``--summary`` renders the
 tracked files plus their env stamps as one table without running
 anything.
 
-``--trace`` wraps each bench in a Chrome-trace capture and writes
-``TRACE_<name>.json`` (load in Perfetto / chrome://tracing; DESIGN.md
-§15).  ``--metrics-check`` runs the suite with metrics ENABLED and
-asserts the final snapshot round-trips through JSON with the §15 schema
-and live dispatch counters — the CI hook that keeps the instrumentation
-from rotting silently.
+``--metrics-check`` runs the suite with metrics ENABLED and asserts the
+final snapshot round-trips through JSON with the §15 schema, live
+dispatch counters and live span counters — the CI hook that keeps the
+instrumentation from rotting silently.
 
 A failing sub-benchmark no longer aborts the rest of the suite: every bench
 runs, every failure is reported, and the process exits non-zero at the end,
@@ -132,18 +130,23 @@ def check_metrics_snapshot() -> None:
         f"no dispatch.*.calls counters recorded; counters="
         f"{sorted(snap['counters'])}"
     )
-    seconds = [
-        k for k in snap["histograms"]
-        if k.endswith(".seconds") and snap["histograms"][k]["count"] > 0
+    # a span (repro.obs.tracing) adds <name>.calls and <name>.seconds
+    spans = [
+        k[: -len(".calls")] for k, v in snap["counters"].items()
+        if k.endswith(".calls") and not k.startswith("dispatch.") and v > 0
+        and snap["counters"].get(k[: -len(".calls")] + ".seconds", 0) > 0
     ]
-    assert seconds, "no populated *.seconds histograms recorded"
+    assert spans, (
+        f"no live span counters (<name>.calls + <name>.seconds) recorded; "
+        f"counters={sorted(snap['counters'])}"
+    )
     for hist in snap["histograms"].values():
         missing = {"count", "sum", "mean", "min", "max", "p50", "p90",
                    "p99"} - set(hist)
         assert not missing, f"histogram summary missing {sorted(missing)}"
     print(
         f"metrics-check,OK,{len(dispatch_calls)} dispatch counters + "
-        f"{len(seconds)} latency histograms live"
+        f"{len(spans)} spans live"
     )
 
 
@@ -154,8 +157,6 @@ def main() -> None:
                     help="tiny sizes: just prove every bench still runs")
     ap.add_argument("--only", default=None,
                     help=f"comma list of benchmarks: {','.join(SUITE)}")
-    ap.add_argument("--trace", action="store_true",
-                    help="write a Chrome-trace TRACE_<name>.json per bench")
     ap.add_argument("--metrics-check", action="store_true",
                     help="run with metrics enabled; assert the snapshot "
                          "parses with the DESIGN.md §15 schema (CI hook)")
@@ -182,8 +183,6 @@ def main() -> None:
         selected = [n for n in selected if n != "obs"]
         metrics.reset()
         metrics.enable()
-    if args.trace:
-        from repro.obs import tracing
 
     enable_compilation_cache()
     print("name,us_per_call,derived")
@@ -191,16 +190,7 @@ def main() -> None:
     for name in selected:
         try:
             mod = importlib.import_module(f"benchmarks.{SUITE[name]}")
-            if args.trace:
-                tracing.start_trace()
-                try:
-                    mod.run(full=args.full, smoke=args.smoke)
-                finally:
-                    tracing.stop_trace()
-                    path = tracing.write_trace(f"TRACE_{name}.json")
-                    print(f"trace,{name},{path}", file=sys.stderr)
-            else:
-                mod.run(full=args.full, smoke=args.smoke)
+            mod.run(full=args.full, smoke=args.smoke)
         except Exception:
             failures.append(name)
             print(f"BENCH-FAILED,{name}", file=sys.stderr)

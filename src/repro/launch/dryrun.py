@@ -271,11 +271,13 @@ def run_cell(
         return record
 
     try:
-        with tracing.span("dryrun.compile", cell=f"{arch_id}/{shape_name}") as sp:
+        watch = tracing.Stopwatch()
+        watch.start()
+        with tracing.span("dryrun.compile", cell=f"{arch_id}/{shape_name}"):
             compiled, meta = lower_cell(arch_id, shape_name, multi_pod,
                                         overrides, tp, grad_accum)
         chips = meta["chips"]
-        record["compile_s"] = round(sp.elapsed_s, 1)
+        record["compile_s"] = round(watch.stop(), 1)
         record["memory_analysis"] = _memory_dict(compiled)
         try:
             ca = compiled.cost_analysis()

@@ -1,13 +1,11 @@
-"""repro.obs — runtime observability: metrics registry + span tracing.
+"""repro.obs — runtime observability: metrics registry + program spans.
 
 DESIGN.md §15.  ``metrics`` holds the process-global Counter/Gauge/
 Histogram registry (a no-op until ``metrics.enable()``); ``tracing``
-provides the ``span()`` context manager and Chrome-trace capture
-(``start_trace()`` → ``write_trace(path)`` → load in Perfetto);
-``format`` is the shared report-line vocabulary.
-
-Importing this package wires the tracing hook into the metrics seam
-timers, so dispatch seams appear in trace captures automatically.
+provides ``span()``, which while the registry records puts a
+``repro/<name>`` annotation on the ``jax.profiler`` clock and adds
+``<name>.seconds`` / ``<name>.calls`` counters; ``format`` is the shared
+report-line vocabulary.
 """
 
 from repro.obs import format, metrics, tracing

@@ -1,10 +1,11 @@
 """Process-global metrics: Counter / Gauge / Histogram with a no-op default.
 
 The runtime counterpart of the paper's resource tables (DESIGN.md §15):
-dispatch counts and wall time per registry axis/backend, sparse-compaction
+dispatch counts per registry axis/backend, span seconds and calls
+(``repro.obs.tracing``), device-to-host bytes, sparse-compaction
 state-machine counters, window-cache hit rates, batch-size and latency
-histograms — the numbers the mesh-sharded serve path (ROADMAP item 3) will
-report through.
+histograms — the numbers the benchmark's per-layer readers and the serve
+path's ``--metrics-out`` report through.
 
 Everything here is host-side Python state (ints, floats, bin lists) behind
 one lock; no jax array is ever stored.  Two invariants keep the module
@@ -33,6 +34,9 @@ import threading
 import time
 from typing import Callable, Dict, Optional
 
+import jax
+import numpy as np
+
 from repro.compat import trace_state_clean
 
 __all__ = [
@@ -44,6 +48,7 @@ __all__ = [
     "gauge",
     "observe",
     "counter_value",
+    "to_host",
     "timed",
     "seam",
     "wrap_backend",
@@ -63,17 +68,6 @@ _HISTS: Dict[str, "_Hist"] = {}
 # 1e9, wide enough for sub-µs seam timings and 10^9-item batch sizes on
 # the same scale.  ~65 edges -> one small int list per histogram.
 _EDGES = tuple(10.0 ** (e / 4.0) for e in range(-28, 37))
-
-# Hooks installed by repro.obs.tracing at import (avoids an import cycle):
-# seam timers also emit Chrome-trace events while a capture is active.
-_trace_active: Callable[[], bool] = lambda: False
-_trace_emit: Callable[..., None] = lambda name, t0, dur, args=None: None
-
-
-def _install_trace_hook(active: Callable[[], bool], emit: Callable) -> None:
-    global _trace_active, _trace_emit
-    _trace_active, _trace_emit = active, emit
-
 
 class _Hist:
     """Log-binned histogram: count/sum/min/max + percentile estimates."""
@@ -213,6 +207,19 @@ def counter_value(name: str) -> float:
         return _COUNTERS.get(name, 0)
 
 
+def to_host(x, dtype=None) -> np.ndarray:
+    """``np.asarray(x, dtype)``, counting a device array's bytes.
+
+    A ``jax.Array`` input is a device-to-host copy: its ``nbytes`` go to
+    the ``transfer.d2h_bytes`` counter.  Host arrays are not copies and
+    count nothing.  A repeated read of the same device array counts each
+    time, though jax may serve it from its host-side cache.
+    """
+    if _ENABLED and isinstance(x, jax.Array):
+        inc("transfer.d2h_bytes", x.nbytes)
+    return np.asarray(x, dtype)
+
+
 # ---------------------------------------------------------------------------
 # timers
 
@@ -234,12 +241,10 @@ _NULL = _NullTimer()
 
 
 class _Timer:
-    __slots__ = ("_counter", "_hist", "_trace", "_t0", "elapsed_s")
+    __slots__ = ("_hist", "_t0", "elapsed_s")
 
-    def __init__(self, counter, hist, trace):
-        self._counter = counter
+    def __init__(self, hist):
         self._hist = hist
-        self._trace = trace
         self.elapsed_s = 0.0
 
     def __enter__(self):
@@ -247,19 +252,8 @@ class _Timer:
         return self
 
     def __exit__(self, *exc):
-        dur = time.perf_counter() - self._t0
-        self.elapsed_s = dur
-        if self._counter is not None or self._hist is not None:
-            with _LOCK:
-                if self._counter is not None:
-                    _COUNTERS[self._counter] = _COUNTERS.get(self._counter, 0) + 1
-                if self._hist is not None:
-                    hist = _HISTS.get(self._hist)
-                    if hist is None:
-                        hist = _HISTS[self._hist] = _Hist()
-                    hist.add(dur)
-        if self._trace is not None:
-            _trace_emit(self._trace, self._t0, dur)
+        self.elapsed_s = time.perf_counter() - self._t0
+        observe(self._hist, self.elapsed_s)
         return False
 
 
@@ -267,34 +261,21 @@ def timed(name: str) -> "_Timer":
     """Context manager feeding histogram ``name`` with wall seconds."""
     if not recording():
         return _NULL
-    return _Timer(None, name, None)
+    return _Timer(name)
 
 
-def seam(axis: str, backend: str) -> "_Timer":
-    """Timer for one dispatch seam: ``dispatch.{axis}.{backend}``.
+def seam(axis: str, backend: str) -> None:
+    """Count one dispatch through a seam: ``dispatch.{axis}.{backend}.calls``.
 
-    Records a ``.calls`` counter and a ``.seconds`` histogram when metrics
-    are enabled, and a Chrome-trace event while a trace capture is active
-    — both gated off under an active jax trace.  Seconds are host dispatch
-    wall time (includes compilation on first call; excludes device
-    completion unless the caller blocks).
+    Gated like every record site (off unless enabled, skipped under an
+    active jax trace).  How long a phase of host work takes is a span's
+    job (``repro.obs.tracing``), not the seam's.
     """
-    live_m = _ENABLED
-    live_t = _trace_active()
-    if not (live_m or live_t):
-        return _NULL
-    if not trace_state_clean():
-        return _NULL
-    key = f"dispatch.{axis}.{backend}"
-    return _Timer(
-        key + ".calls" if live_m else None,
-        key + ".seconds" if live_m else None,
-        f"{axis}[{backend}]" if live_t else None,
-    )
+    inc(f"dispatch.{axis}.{backend}.calls")
 
 
 def wrap_backend(axis: str, name: str, fn: Callable) -> Callable:
-    """Wrap a registry backend so every real dispatch is counted + timed.
+    """Wrap a registry backend so every real dispatch is counted.
 
     Applied once at registration (``repro.sketch.plan.register_*``), so
     the per-dispatch cost when disabled is one extra frame + flag check.
@@ -304,10 +285,9 @@ def wrap_backend(axis: str, name: str, fn: Callable) -> Callable:
 
     @functools.wraps(fn)
     def dispatch(*args, **kwargs):
-        if not (_ENABLED or _trace_active()):
-            return fn(*args, **kwargs)
-        with seam(axis, name):
-            return fn(*args, **kwargs)
+        if _ENABLED:
+            seam(axis, name)
+        return fn(*args, **kwargs)
 
     dispatch.__sketch_backend__ = fn
     return dispatch

@@ -1,118 +1,72 @@
-"""Nested span tracing → Chrome-trace-event JSON, plus shared timer helpers.
+"""Program spans on the profiler's clock, plus the shared Stopwatch helper.
 
-``span("name")`` is the one timing idiom for launch/train/bench code
-(replacing the hand-rolled ``perf_counter`` pairs): it always measures
-``elapsed_s``; while a capture started by :func:`start_trace` is active it
-also appends a Chrome ``"X"`` (complete) event, and ``metric=`` feeds the
-duration into a metrics histogram when metrics are enabled.  Nesting needs
-no bookkeeping — Perfetto reconstructs the stack from overlapping
-``ts``/``dur`` ranges per thread.
+``span("layer.phase")`` marks where the host work of one layer happens.
+While the metrics registry is recording (:func:`repro.obs.metrics.recording`:
+enabled and no active jax trace) a span
 
-:func:`chrome_trace` / :func:`write_trace` emit the ``{"traceEvents":
-[...]}`` JSON that Perfetto (https://ui.perfetto.dev) and
-``chrome://tracing`` load directly.  The event buffer is host-side only;
-span bodies that run under an active jax trace record nothing (same
-hygiene gate as the metrics registry, DESIGN.md §15).
+* opens a ``jax.profiler.TraceAnnotation("repro/" + name)``, so a
+  ``jax.profiler`` capture shows it on the host plane, on the same clock as
+  the device's ops, where it can name a device idle gap;
+* adds its wall seconds and one call to the registry counters
+  ``<name>.seconds`` and ``<name>.calls``;
+* feeds ``metric=`` (a histogram name) with its wall seconds.
+
+When the registry is not recording, ``span`` returns one shared null
+context after a single flag check, whose ``elapsed_s`` reads 0.0: code
+that reports a wall time whether or not the registry records times it
+with :class:`Stopwatch`.  Under an active jax trace nothing is recorded,
+so tracing a jitted caller neither leaks tracers nor books work the
+compiled executable replays without running Python (DESIGN.md §15).
 """
 
 from __future__ import annotations
 
-import json
-import os
-import threading
 import time
 from typing import Optional
 
-from repro.compat import trace_state_clean
+from jax.profiler import TraceAnnotation
+
 from repro.obs import metrics as _metrics
 
-__all__ = [
-    "span",
-    "Stopwatch",
-    "start_trace",
-    "stop_trace",
-    "active",
-    "chrome_trace",
-    "write_trace",
-]
+__all__ = ["span", "Stopwatch", "PREFIX"]
 
-_LOCK = threading.Lock()
-_EVENTS: list = []
-_ACTIVE = False
-_T0 = 0.0
+PREFIX = "repro/"  # profiler event names: PREFIX + span name
 
 
-def start_trace() -> None:
-    """Begin a capture: clears the buffer and timestamps events from now."""
-    global _ACTIVE, _T0
-    with _LOCK:
-        _EVENTS.clear()
-        _T0 = time.perf_counter()
-        _ACTIVE = True
+class _Span:
+    __slots__ = ("name", "metric", "elapsed_s", "_note", "_t0")
 
-
-def stop_trace() -> list:
-    """End the capture; returns the buffered events (buffer is kept)."""
-    global _ACTIVE
-    with _LOCK:
-        _ACTIVE = False
-        return list(_EVENTS)
-
-
-def active() -> bool:
-    return _ACTIVE
-
-
-def _emit(name: str, t0: float, dur_s: float, args: Optional[dict] = None):
-    if not _ACTIVE or not trace_state_clean():
-        return
-    event = {
-        "name": name,
-        "ph": "X",
-        "ts": (t0 - _T0) * 1e6,
-        "dur": dur_s * 1e6,
-        "pid": os.getpid(),
-        "tid": threading.get_ident(),
-    }
-    if args:
-        event["args"] = {k: str(v) for k, v in args.items()}
-    with _LOCK:
-        if _ACTIVE:
-            _EVENTS.append(event)
-
-
-# dispatch-seam timers (metrics.seam / wrap_backend) emit through us too,
-# so a --trace capture shows backend dispatches under the outer spans
-_metrics._install_trace_hook(active, _emit)
-
-
-class span:
-    """Context-manager timer; emits a Chrome event while a trace is active.
-
-    ``with span("prefill") as t: ...`` then read ``t.elapsed_s``.  Pass
-    ``metric="serve.request.seconds"`` to also feed a metrics histogram
-    (no-op unless metrics are enabled); extra keyword arguments land in
-    the event's ``args`` payload.
-    """
-
-    __slots__ = ("name", "metric", "args", "elapsed_s", "_t0")
-
-    def __init__(self, name: str, *, metric: Optional[str] = None, **args):
+    def __init__(self, name: str, metric: Optional[str], args: dict):
         self.name = name
         self.metric = metric
-        self.args = args or None
         self.elapsed_s = 0.0
+        self._note = TraceAnnotation(PREFIX + name, **args)
 
-    def __enter__(self) -> "span":
+    def __enter__(self) -> "_Span":
+        self._note.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.elapsed_s = time.perf_counter() - self._t0
-        _emit(self.name, self._t0, self.elapsed_s, self.args)
+        self._note.__exit__(*exc)
+        _metrics.inc(self.name + ".seconds", self.elapsed_s)
+        _metrics.inc(self.name + ".calls")
         if self.metric is not None:
             _metrics.observe(self.metric, self.elapsed_s)
         return False
+
+
+def span(name: str, *, metric: Optional[str] = None, **args):
+    """Context manager around one phase of host work named ``name``.
+
+    ``with span("sparse.route"): ...``; read ``.elapsed_s`` on the value
+    it returns.  Extra keyword arguments are attached to the profiler
+    event as metadata.
+    """
+    if not _metrics.recording():
+        return _metrics._NULL  # shared do-nothing context, elapsed_s 0.0
+    return _Span(name, metric, args)
 
 
 class Stopwatch:
@@ -143,16 +97,3 @@ class Stopwatch:
         dt = self.elapsed()
         self._t0 = None
         return dt
-
-
-def chrome_trace() -> dict:
-    """The capture as a Chrome-trace dict (Perfetto-loadable as JSON)."""
-    with _LOCK:
-        events = list(_EVENTS)
-    return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
-def write_trace(path: str) -> str:
-    with open(path, "w") as f:
-        json.dump(chrome_trace(), f)
-    return path

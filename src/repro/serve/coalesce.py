@@ -31,6 +31,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.obs import metrics as obs_metrics
+from repro.obs.metrics import to_host
 
 __all__ = ["CoalescingQueue", "DoubleBuffer", "SharedWindowRing"]
 
@@ -73,8 +74,8 @@ class CoalescingQueue:
     def submit(self, keys, items) -> int:
         """Queue one tenant batch (host append, no device work); returns
         the number of items pending after the append."""
-        keys = np.asarray(keys).reshape(-1).astype(np.int32, copy=False)
-        items = np.asarray(items).reshape(-1)
+        keys = to_host(keys).reshape(-1).astype(np.int32, copy=False)
+        items = to_host(items).reshape(-1)
         if keys.shape[0] != items.shape[0]:
             raise ValueError(
                 f"keys ({keys.shape[0]}) and items ({items.shape[0]}) "
@@ -87,7 +88,7 @@ class CoalescingQueue:
 
     def submit_row(self, row: int, items) -> int:
         """``submit`` with every item routed to one tenant row."""
-        items = np.asarray(items).reshape(-1)
+        items = to_host(items).reshape(-1)
         return self.submit(np.full(items.shape[0], row, np.int32), items)
 
     def pending_batches(self) -> int:

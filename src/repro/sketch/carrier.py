@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.tracing import span
 from repro.sketch import hll, setops, u64 as u64lib
 from repro.sketch.dispatch import update_registers
 from repro.sketch.hll import HLLConfig
@@ -89,12 +90,14 @@ class HyperLogLog:
         A zero-length batch returns ``self`` without dispatching any
         backend (the update is the lattice identity).
         """
-        if items.size == 0:
-            return self
-        regs = update_registers(self.registers, items, self.cfg, plan)
-        return dataclasses.replace(
-            self, registers=regs, n_items=_counter_add(self.n_items, items.size)
-        )
+        with span("hll.update"):
+            if items.size == 0:
+                return self
+            with span("hll.update.registers"):
+                regs = update_registers(self.registers, items, self.cfg, plan)
+            with span("hll.update.counter"):
+                n_items = _counter_add(self.n_items, items.size)
+            return dataclasses.replace(self, registers=regs, n_items=n_items)
 
     def merge(self, other: "HyperLogLog") -> "HyperLogLog":
         """Merge-buckets fold: element-wise max; counters add exactly."""

@@ -461,12 +461,12 @@ def estimate(
 ) -> float:
     """Phase 4, host-exact: histogram the registers, then finalize."""
     name = resolve_estimator(estimator)
-    # finalization time per estimator (DESIGN.md §15) — the "estimate"
-    # axis reuses the dispatch-seam shape the backend registries get from
+    # finalizations per estimator (DESIGN.md §15) — the "estimate" axis
+    # reuses the dispatch-seam counter the backend registries get from
     # plan.register_*, with the estimator name in the backend slot
-    with obs_metrics.seam("estimate", name):
-        counts = register_histogram_host(registers, cfg)
-        return float(get_estimator(name).host(counts, cfg))
+    obs_metrics.seam("estimate", name)
+    counts = register_histogram_host(registers, cfg)
+    return float(get_estimator(name).host(counts, cfg))
 
 
 @partial(jax.jit, static_argnames=("cfg", "estimator"))
@@ -485,8 +485,8 @@ def estimate_device(
     """Float32 on-device estimate of one (m,) sketch (telemetry path)."""
     validate_registers(registers, cfg, batched=False)
     name = resolve_estimator(estimator)
-    with obs_metrics.seam("estimate", name):
-        return _estimate_device(registers, cfg, name)
+    obs_metrics.seam("estimate", name)
+    return _estimate_device(registers, cfg, name)
 
 
 def estimate_many(
@@ -503,5 +503,5 @@ def estimate_many(
     """
     validate_registers(register_bank, cfg, batched=True)
     name = resolve_estimator(estimator)
-    with obs_metrics.seam("estimate", name):
-        return _estimate_device(register_bank, cfg, name)
+    obs_metrics.seam("estimate", name)
+    return _estimate_device(register_bank, cfg, name)

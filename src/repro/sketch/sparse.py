@@ -89,6 +89,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import metrics as obs_metrics
+from repro.obs.metrics import to_host
+from repro.obs.tracing import span
 from repro.sketch import hll, u64 as u64lib
 from repro.sketch.bank import (
     _BANK_HEADER,
@@ -152,6 +154,14 @@ def _check_cell_space(rows: int, m: int) -> None:
             f"bank cell space B*m = {rows}*{m} overflows int32 sort "
             f"cells; split the fleet across multiple banks"
         )
+
+
+def _pending_pressure(pending, pair_len) -> bool:
+    """True once the buffer passes both flush floors (module note)."""
+    if pending is None or pending.total < _FLUSH_MIN_PAIRS:
+        return False
+    live = int(to_host(pair_len, np.int64).sum())
+    return pending.total >= max(_FLUSH_MIN_PAIRS, _FLUSH_FACTOR * live)
 
 
 def _fit_capacity(needed: int, threshold: int) -> int:
@@ -289,8 +299,8 @@ def _dedup_products(
     call sites do.
     """
     if dd.cells is not None:
-        cells_np = np.asarray(dd.cells)
-        pairs = _compact_cells(cells_np, keep, np.asarray(dd.distinct), cap=cap)
+        cells_np = to_host(dd.cells)
+        pairs = _compact_cells(cells_np, keep, to_host(dd.distinct), cap=cap)
         dense = (
             jnp.asarray(
                 cells_np[np.nonzero(slot_of_row >= 0)[0]].astype(
@@ -430,13 +440,13 @@ class HybridBank:
         threshold = _check_threshold(
             default_threshold(cfg) if threshold is None else threshold, cfg
         )
-        regs = np.asarray(bank.registers)
+        regs = to_host(bank.registers)
         rows = regs.shape[0]
         occ = (regs > 0).sum(axis=1).astype(np.int64)
         force = (
             np.zeros(rows, bool)
             if dense_rows is None
-            else np.asarray(dense_rows, bool)
+            else to_host(dense_rows, bool)
         )
         if force.shape != (rows,):
             raise ValueError(
@@ -484,14 +494,6 @@ class HybridBank:
         """Raw (bucket, rank) appends buffered since the last compaction."""
         return 0 if self.pending is None else self.pending.total
 
-    def _pending_pressure(self) -> bool:
-        """True once the buffer passes both flush floors (module note)."""
-        pend = self.pending
-        if pend is None or pend.total < _FLUSH_MIN_PAIRS:
-            return False
-        live = int(np.asarray(self.pair_len, dtype=np.int64).sum())
-        return pend.total >= max(_FLUSH_MIN_PAIRS, _FLUSH_FACTOR * live)
-
     def compact(self, _reason: str = "read") -> "HybridBank":
         """Settle the append buffer: dedup, recompact, promote — one pass.
 
@@ -510,35 +512,39 @@ class HybridBank:
         cached = self.__dict__.get("_settled")
         if cached is None:
             obs_metrics.inc(f"sparse.flush.{_reason}")
-            cached = self._compact_now()
+            with span(f"sparse.compact.{_reason}"):
+                cached = self._compact_now()
             object.__setattr__(self, "_settled", cached)
         return cached
 
     def _compact_now(self) -> "HybridBank":
         pend = self.pending
         rows, m = len(self), self.cfg.m
-        keys_np = np.concatenate([k for k, _ in pend.chunks])
-        items_np = np.concatenate([v for _, v in pend.chunks])
-        n = keys_np.size
-        # pow2 padding (row = -1, dropped by the dedup validity mask)
-        # bounds jit recompiles of the hash and dedup kernels
-        pad = 1 << max(6, (n - 1).bit_length()) if n else 64
-        items_pad = np.zeros(pad, items_np.dtype)
-        items_pad[:n] = items_np
-        new_rows = np.full(pad, -1, np.int32)
-        new_rows[:n] = keys_np
-        idx, rank = _hash_stream(jnp.asarray(items_pad), self.cfg)
-        old_rows, old_buckets, old_ranks = self._pair_triples()
-        dd = dedup_pairs(
-            jnp.concatenate([jnp.asarray(old_rows), jnp.asarray(new_rows)]),
-            jnp.concatenate([jnp.asarray(old_buckets), idx]),
-            jnp.concatenate([jnp.asarray(old_ranks), rank]),
-            rows,
-            self.cfg,
-            pend.plan,
-        )
-        distinct_np = np.asarray(dd.distinct)
-        slot_np = np.asarray(self.slot_map)
+        with span("sparse.compact.hash"):
+            keys_np = np.concatenate([k for k, _ in pend.chunks])
+            items_np = np.concatenate([v for _, v in pend.chunks])
+            n = keys_np.size
+            # pow2 padding (row = -1, dropped by the dedup validity mask)
+            # bounds jit recompiles of the hash and dedup kernels
+            pad = 1 << max(6, (n - 1).bit_length()) if n else 64
+            items_pad = np.zeros(pad, items_np.dtype)
+            items_pad[:n] = items_np
+            new_rows = np.full(pad, -1, np.int32)
+            new_rows[:n] = keys_np
+            idx, rank = _hash_stream(jnp.asarray(items_pad), self.cfg)
+        with span("sparse.compact.pairs"):
+            old_rows, old_buckets, old_ranks = self._pair_triples()
+        with span("sparse.compact.dedup"):
+            dd = dedup_pairs(
+                jnp.concatenate([jnp.asarray(old_rows), jnp.asarray(new_rows)]),
+                jnp.concatenate([jnp.asarray(old_buckets), idx]),
+                jnp.concatenate([jnp.asarray(old_ranks), rank]),
+                rows,
+                self.cfg,
+                pend.plan,
+            )
+            distinct_np = to_host(dd.distinct)
+            slot_np = to_host(self.slot_map)
         was_sparse = slot_np < 0
         promote = was_sparse & (distinct_np > self.threshold)
         keep = was_sparse & ~promote
@@ -550,29 +556,30 @@ class HybridBank:
             obs_metrics.inc("sparse.promotions", int(promoted.size))
         slot_of_row = np.full(rows, -1, np.int32)
         slot_of_row[promoted] = np.arange(promoted.size, dtype=np.int32)
-        new_pairs, fresh = _dedup_products(
-            dd, keep, slot_of_row, rows=rows, m=m, cap=cap, slots=promoted.size
-        )
-        new_dense = self.dense_block
-        new_slot = slot_np
-        if promoted.size:
-            new_dense = (
-                jnp.concatenate([new_dense, fresh])
-                if new_dense.shape[0]
-                else fresh
+        with span("sparse.compact.products"):
+            new_pairs, fresh = _dedup_products(
+                dd, keep, slot_of_row, rows=rows, m=m, cap=cap, slots=promoted.size
             )
-            new_slot = slot_np.copy()
-            new_slot[promoted] = self.dense_block.shape[0] + np.arange(
-                promoted.size, dtype=np.int32
+            new_dense = self.dense_block
+            new_slot = slot_np
+            if promoted.size:
+                new_dense = (
+                    jnp.concatenate([new_dense, fresh])
+                    if new_dense.shape[0]
+                    else fresh
+                )
+                new_slot = slot_np.copy()
+                new_slot[promoted] = self.dense_block.shape[0] + np.arange(
+                    promoted.size, dtype=np.int32
+                )
+            return dataclasses.replace(
+                self,
+                pair_buf=new_pairs,
+                pair_len=jnp.asarray(np.where(keep, distinct_np, 0).astype(np.int32)),
+                dense_block=new_dense,
+                slot_map=jnp.asarray(new_slot),
+                pending=None,
             )
-        return dataclasses.replace(
-            self,
-            pair_buf=new_pairs,
-            pair_len=jnp.asarray(np.where(keep, distinct_np, 0).astype(np.int32)),
-            dense_block=new_dense,
-            slot_map=jnp.asarray(new_slot),
-            pending=None,
-        )
 
     # ------------------------------------------------------------------
     # introspection (every surface reads the SETTLED state)
@@ -614,7 +621,7 @@ class HybridBank:
     @property
     def modes(self) -> np.ndarray:
         """(B,) uint8 row modes: MODE_SPARSE (0) or MODE_DENSE (1)."""
-        return (np.asarray(self.compact().slot_map) >= 0).astype(np.uint8)
+        return (to_host(self.compact().slot_map) >= 0).astype(np.uint8)
 
     @property
     def counts(self) -> np.ndarray:
@@ -623,7 +630,7 @@ class HybridBank:
         Counters update eagerly at ingest (one bincount per batch), so
         they never wait on a compaction.
         """
-        limbs = np.asarray(self.n_items)
+        limbs = to_host(self.n_items)
         hi = limbs[:, 0].astype(np.uint64)
         lo = limbs[:, 1].astype(np.uint64)
         return (hi << np.uint64(32)) | lo
@@ -646,10 +653,10 @@ class HybridBank:
         rows = len(s)
         m = s.cfg.m
         d = int(s.dense_block.shape[0])
-        occ = np.asarray(s.pair_len).astype(np.int64)
+        occ = to_host(s.pair_len).astype(np.int64)
         if d:
-            dense_occ = (np.asarray(s.dense_block) > 0).sum(axis=1)
-            slot_np = np.asarray(s.slot_map)
+            dense_occ = (to_host(s.dense_block) > 0).sum(axis=1)
+            slot_np = to_host(s.slot_map)
             occ = occ + np.zeros_like(occ)
             occ[slot_np >= 0] = dense_occ[slot_np[slot_np >= 0]]
         dense_nbytes = rows * m + rows * 8  # what a SketchBank would cost
@@ -677,7 +684,7 @@ class HybridBank:
             regs = s.dense_block[slot]
         else:
             regs_np = np.zeros(s.cfg.m, np.uint8)
-            p = np.asarray(s.pair_buf[i])
+            p = to_host(s.pair_buf[i])
             p = p[p >= 0]
             regs_np[p >> _PACK_SHIFT] = (p & _PACK_MASK).astype(np.uint8)
             regs = jnp.asarray(regs_np)
@@ -698,7 +705,7 @@ class HybridBank:
         (row = -1, dropped by the dedup validity mask) bounds jit
         recompiles.
         """
-        pairs_np = np.asarray(self.pair_buf)
+        pairs_np = to_host(self.pair_buf)
         rows_np, slots = np.nonzero(pairs_np >= 0)
         packed = pairs_np[rows_np, slots]
         p = packed.size
@@ -755,57 +762,58 @@ class HybridBank:
         streams and zero-row banks return ``self`` without dispatching
         any backend.
         """
-        keys_np = np.asarray(keys).reshape(-1)
-        items_np = np.asarray(items).reshape(-1)
-        if keys_np.shape[0] != items_np.shape[0]:
-            raise ValueError(
-                f"keys ({keys_np.shape[0]}) and items "
-                f"({items_np.shape[0]}) must flatten to the same length"
+        with span("sparse.route"):
+            keys_np = to_host(keys).reshape(-1)
+            items_np = to_host(items).reshape(-1)
+            if keys_np.shape[0] != items_np.shape[0]:
+                raise ValueError(
+                    f"keys ({keys_np.shape[0]}) and items "
+                    f"({items_np.shape[0]}) must flatten to the same length"
+                )
+            rows = len(self)
+            if items_np.shape[0] == 0 or rows == 0:
+                return self
+            _check_cell_space(rows, self.cfg.m)
+            plan = (DEFAULT_PLAN if plan is None else plan).validate()
+            keys_np = keys_np.astype(np.int32, copy=False)
+            slot_np = to_host(self.slot_map)
+            valid = (keys_np >= 0) & (keys_np < rows)
+            dest = np.where(valid, slot_np[np.clip(keys_np, 0, rows - 1)], -1)
+            dense_sel = valid & (dest >= 0)
+            sparse_sel = valid & (dest < 0)
+
+            pending = self.pending
+            if sparse_sel.any():
+                appended = int(sparse_sel.sum())
+                chunk = (keys_np[sparse_sel], items_np[sparse_sel])
+                chunks = (chunk,) if pending is None else pending.chunks + (chunk,)
+                total = appended + (pending.total if pending else 0)
+                pending = _PendingLog(chunks, total, plan)
+                obs_metrics.inc("sparse.pending.appends")
+                obs_metrics.inc("sparse.pending.pairs", appended)
+
+            # one host bincount keeps the counters exact without a device
+            # round-trip on the pure-append path
+            counts = np.bincount(keys_np[valid], minlength=rows)[:rows]
+            n_items = _counter_add_rows(
+                self.n_items, jnp.asarray(counts.astype(np.uint32))
             )
-        rows = len(self)
-        if items_np.shape[0] == 0 or rows == 0:
-            return self
-        _check_cell_space(rows, self.cfg.m)
-        plan = (DEFAULT_PLAN if plan is None else plan).validate()
-        keys_np = keys_np.astype(np.int32, copy=False)
-        slot_np = np.asarray(self.slot_map)
-        valid = (keys_np >= 0) & (keys_np < rows)
-        dest = np.where(valid, slot_np[np.clip(keys_np, 0, rows - 1)], -1)
-        dense_sel = valid & (dest >= 0)
-        sparse_sel = valid & (dest < 0)
+            pressure = _pending_pressure(pending, self.pair_len)
 
         new_dense = self.dense_block
         if dense_sel.any():
-            new_dense = update_bank_registers(
-                self.dense_block,
-                jnp.asarray(dest[dense_sel]),
-                jnp.asarray(items_np[dense_sel]),
-                self.cfg,
-                plan,
-            )
-
-        pending = self.pending
-        if sparse_sel.any():
-            appended = int(sparse_sel.sum())
-            chunk = (keys_np[sparse_sel], items_np[sparse_sel])
-            chunks = (chunk,) if pending is None else pending.chunks + (chunk,)
-            total = appended + (pending.total if pending else 0)
-            pending = _PendingLog(chunks, total, plan)
-            obs_metrics.inc("sparse.pending.appends")
-            obs_metrics.inc("sparse.pending.pairs", appended)
-
-        # one host bincount keeps the counters exact without a device
-        # round-trip on the pure-append path
-        counts = np.bincount(keys_np[valid], minlength=rows)[:rows]
+            with span("sparse.dense"):
+                new_dense = update_bank_registers(
+                    self.dense_block,
+                    jnp.asarray(dest[dense_sel]),
+                    jnp.asarray(items_np[dense_sel]),
+                    self.cfg,
+                    plan,
+                )
         out = dataclasses.replace(
-            self,
-            dense_block=new_dense,
-            n_items=_counter_add_rows(
-                self.n_items, jnp.asarray(counts.astype(np.uint32))
-            ),
-            pending=pending,
+            self, dense_block=new_dense, n_items=n_items, pending=pending
         )
-        if out._pending_pressure():
+        if pressure:
             return out.compact(_reason="pressure")
         return out
 
@@ -851,8 +859,8 @@ class HybridBank:
             return dataclasses.replace(a, n_items=n_items)
         _check_cell_space(rows, m)
         plan = (DEFAULT_PLAN if plan is None else plan).validate()
-        slot_a = np.asarray(a.slot_map)
-        slot_b = np.asarray(b.slot_map)
+        slot_a = to_host(a.slot_map)
+        slot_b = to_host(b.slot_map)
         force_dense = (slot_a >= 0) | (slot_b >= 0)
         # a row dense on one side still contributes the OTHER side's pairs
         # through the triple stream; its dense registers overlay below
@@ -866,7 +874,7 @@ class HybridBank:
             a.cfg,
             plan,
         )
-        distinct_np = np.asarray(dd.distinct)
+        distinct_np = to_host(dd.distinct)
         promote = ~force_dense & (distinct_np > a.threshold)
         keep = ~force_dense & ~promote
         cap = _fit_capacity(int(distinct_np[keep].max(initial=0)), a.threshold)
@@ -1003,11 +1011,11 @@ class HybridBank:
         )
         out = [header, _THRESHOLD.pack(s.threshold)]
         out.append(s.counts.astype("<u8").tobytes())
-        modes = (np.asarray(s.slot_map) >= 0).astype(np.uint8)
+        modes = (to_host(s.slot_map) >= 0).astype(np.uint8)
         out.append(modes.tobytes())
-        pairs_np = np.asarray(s.pair_buf)
-        dense_np = np.asarray(s.dense_block, dtype=np.uint8)
-        slot_np = np.asarray(s.slot_map)
+        pairs_np = to_host(s.pair_buf)
+        dense_np = to_host(s.dense_block, dtype=np.uint8)
+        slot_np = to_host(s.slot_map)
         for i in range(rows):
             if modes[i] == MODE_DENSE:
                 out.append(dense_np[slot_np[i]].tobytes())
