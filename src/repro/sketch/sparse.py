@@ -33,7 +33,8 @@ dense).
 ``update_many(keys, items, plan)`` routes the whole keyed stream in one
 pass with no python loop over rows: dense-destined items dispatch through
 the registered bank backend of ``plan`` (the §9 scatter — jnp or the
-Pallas bank kernel), while sparse-destined items land in a per-bank
+Pallas bank kernel) at power-of-two padded shapes, so one executable
+serves many ticks, while sparse-destined items land in a per-bank
 **append buffer** of raw (row, item) entries with NO dedup — an O(new)
 append, so steady-state ingest cost tracks new pairs instead of all live
 pairs.  Dedup runs as a **compaction** step only under capacity pressure
@@ -81,6 +82,7 @@ from __future__ import annotations
 
 import dataclasses
 import struct
+import threading
 from functools import partial
 from typing import Optional, Sequence, Tuple
 
@@ -162,6 +164,17 @@ def _pending_pressure(pending, pair_len) -> bool:
         return False
     live = int(to_host(pair_len, np.int64).sum())
     return pending.total >= max(_FLUSH_MIN_PAIRS, _FLUSH_FACTOR * live)
+
+
+def _pow2_bucket(n: int, floor: int = 6) -> int:
+    """Smallest power of two >= ``n`` and >= 2^floor: the jit-shape buckets
+    that bound recompiles of every padded dispatch in this module."""
+    return 1 << max(floor, (n - 1).bit_length())
+
+
+# (row bucket, length bucket) pairs the dense dispatch has sent this process
+_DENSE_SHAPES_SEEN: set = set()
+_DENSE_SHAPES_LOCK = threading.Lock()
 
 
 def _fit_capacity(needed: int, threshold: int) -> int:
@@ -526,7 +539,7 @@ class HybridBank:
             n = keys_np.size
             # pow2 padding (row = -1, dropped by the dedup validity mask)
             # bounds jit recompiles of the hash and dedup kernels
-            pad = 1 << max(6, (n - 1).bit_length()) if n else 64
+            pad = _pow2_bucket(n)
             items_pad = np.zeros(pad, items_np.dtype)
             items_pad[:n] = items_np
             new_rows = np.full(pad, -1, np.int32)
@@ -709,7 +722,7 @@ class HybridBank:
         rows_np, slots = np.nonzero(pairs_np >= 0)
         packed = pairs_np[rows_np, slots]
         p = packed.size
-        pad = 1 << max(6, (p - 1).bit_length()) if p else 64
+        pad = _pow2_bucket(p)
         row = np.full(pad, -1, np.int32)
         bucket = np.zeros(pad, np.int32)
         rank = np.zeros(pad, np.int32)
@@ -803,12 +816,8 @@ class HybridBank:
         new_dense = self.dense_block
         if dense_sel.any():
             with span("sparse.dense"):
-                new_dense = update_bank_registers(
-                    self.dense_block,
-                    jnp.asarray(dest[dense_sel]),
-                    jnp.asarray(items_np[dense_sel]),
-                    self.cfg,
-                    plan,
+                new_dense = self._dense_update(
+                    dest[dense_sel], items_np[dense_sel], plan
                 )
         out = dataclasses.replace(
             self, dense_block=new_dense, n_items=n_items, pending=pending
@@ -816,6 +825,38 @@ class HybridBank:
         if pressure:
             return out.compact(_reason="pressure")
         return out
+
+    def _dense_update(self, slots, items, plan: ExecutionPlan) -> jnp.ndarray:
+        """Scatter a dense-destined (slot, item) sub-stream at bucketed shapes.
+
+        The backend compiles per input shape, and both the sub-stream's
+        length and the block's D change from tick to tick, so the stream
+        is padded on the host to the next power of two (floor 2^6) with
+        slot -1 / item 0, and the block to the next power of two of D with
+        zero rows.  Slot -1 is dropped by every backend's §9 rule (the
+        sharded placement re-bases it to a still-negative key), and no
+        slot points past D, so the padding lands nothing; the result is
+        sliced back to (D, m) and the stored block keeps its exact shape.
+        """
+        d, n = int(self.dense_block.shape[0]), int(slots.shape[0])
+        d_pad, n_pad = _pow2_bucket(d, floor=0), _pow2_bucket(n)
+        with _DENSE_SHAPES_LOCK:
+            new_shape = (d_pad, n_pad) not in _DENSE_SHAPES_SEEN
+            _DENSE_SHAPES_SEEN.add((d_pad, n_pad))
+        if new_shape:
+            obs_metrics.inc("sparse.dense.new_shapes")
+        obs_metrics.inc("sparse.dense.pad_pairs", n_pad - n)
+        keys = np.full(n_pad, -1, np.int32)
+        keys[:n] = slots
+        vals = np.zeros(n_pad, items.dtype)
+        vals[:n] = items
+        block = self.dense_block
+        if d_pad != d:
+            block = jnp.pad(block, ((0, d_pad - d), (0, 0)))
+        out = update_bank_registers(
+            block, jnp.asarray(keys), jnp.asarray(vals), self.cfg, plan
+        )
+        return out if d_pad == d else out[:d]
 
     def merge(
         self, other: "HybridBank", plan: Optional[ExecutionPlan] = None

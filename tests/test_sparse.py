@@ -31,7 +31,9 @@ from repro.sketch import (
     hll,
     update_many,
 )
+from repro.sketch.bank import update_bank_registers
 from repro.sketch.sparse import MODE_DENSE, MODE_SPARSE
+from tests.reference_model import make_sharded_plans
 
 CFG = HLLConfig(p=8, hash_bits=64)  # m=256: small enough for pallas paths
 
@@ -713,3 +715,112 @@ def test_sparse_scatter_kernel_matches_jnp_dedup(backend):
     np.testing.assert_array_equal(np.asarray(got.distinct), np.asarray(ref.distinct))
     if ref.cells is not None:
         np.testing.assert_array_equal(np.asarray(got.cells), np.asarray(ref.cells))
+
+
+# ----------------------------------------------------------------------------
+# dense dispatch at bucketed shapes (DESIGN.md §12)
+# ----------------------------------------------------------------------------
+
+
+CFG_SMALL = HLLConfig(p=4, hash_bits=64)  # m=16: D=1025 stays cheap in interpret
+
+
+def _bank_with_dense_rows(d, sparse_rows=2, seed=0):
+    """A hybrid bank whose first ``d`` rows are dense and hold some history."""
+    rows = d + sparse_rows
+    rng = np.random.default_rng(seed)
+    hist_keys = rng.integers(0, d, 4 * d, dtype=np.int32)
+    hist_items = rng.integers(0, 2**31, 4 * d, dtype=np.int32)
+    dense = SketchBank.empty(rows, CFG_SMALL).update_many(
+        jnp.asarray(hist_keys), jnp.asarray(hist_items)
+    )
+    force = np.arange(rows) < d
+    return HybridBank.from_dense(dense, dense_rows=jnp.asarray(force)), dense
+
+
+def _plan_for(kind):
+    if kind == "sharded":
+        return make_sharded_plans(["jnp"])["jnp"]
+    return ExecutionPlan(backend=kind)
+
+
+@pytest.mark.parametrize("kind", ["jnp", "pallas", "sharded"])
+@pytest.mark.parametrize("d", [1, 3, 1024, 1025])
+@pytest.mark.parametrize("n", [1, 63, 64, 65, 1023, 1025])
+def test_bucketed_dense_dispatch_matches_exact_shapes(kind, d, n):
+    """Padding the dense sub-stream and block changes no bit of the state."""
+    plan = _plan_for(kind)
+    hb, dense = _bank_with_dense_rows(d, seed=n + d)
+    rng = np.random.default_rng(1000 + n)
+    # n dense-destined pairs plus a few sparse-destined and dropped ones
+    keys = np.concatenate(
+        [rng.integers(0, d, n), [d, d + 1, -1, d + 2]]
+    ).astype(np.int32)
+    items = rng.integers(0, 2**31, keys.size, dtype=np.int32)
+    got = hb.update_many(jnp.asarray(keys), jnp.asarray(items), plan)
+
+    sel = (keys >= 0) & (keys < d)
+    slots = np.asarray(hb.slot_map)[keys[sel]]
+    exact = update_bank_registers(
+        hb.dense_block,
+        jnp.asarray(slots),
+        jnp.asarray(items[sel]),
+        CFG_SMALL,
+        plan,
+    )
+    assert got.dense_block.shape == (d, CFG_SMALL.m)
+    np.testing.assert_array_equal(
+        np.asarray(got.dense_block), np.asarray(exact)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(got.slot_map), np.asarray(hb.slot_map)
+    )
+    want = dense.update_many(jnp.asarray(keys), jnp.asarray(items), plan)
+    np.testing.assert_array_equal(
+        np.asarray(got.n_items), np.asarray(want.n_items)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(got.to_dense().registers), np.asarray(want.registers)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(got.estimate_many()), np.asarray(want.estimate_many())
+    )
+
+
+def test_dense_dispatch_reuses_one_executable_per_bucket(monkeypatch):
+    """Same (D, length) buckets reuse the executable; a D past 2^k adds one."""
+    from repro.obs import metrics
+    from repro.sketch import sparse as sparse_mod
+    from repro.sketch.backends import bank_update_jnp
+
+    monkeypatch.setattr(sparse_mod, "_DENSE_SHAPES_SEEN", set())
+    # a seed no other test uses, so this test's cache entries are its own
+    cfg = HLLConfig(p=4, hash_bits=64, seed=0x5EED0014)
+    rng = np.random.default_rng(14)
+
+    def ingest(d, n):
+        rows = d + 1
+        force = jnp.asarray(np.arange(rows) < d)
+        hb = HybridBank.from_dense(SketchBank.empty(rows, cfg), dense_rows=force)
+        keys = rng.integers(0, d, n).astype(np.int32)
+        items = rng.integers(0, 2**31, n, dtype=np.int32)
+        before = bank_update_jnp._cache_size()
+        seen = metrics.counter_value("sparse.dense.new_shapes")
+        pad = metrics.counter_value("sparse.dense.pad_pairs")
+        hb.update_many(jnp.asarray(keys), jnp.asarray(items))
+        return (
+            bank_update_jnp._cache_size() - before,
+            metrics.counter_value("sparse.dense.new_shapes") - seen,
+            metrics.counter_value("sparse.dense.pad_pairs") - pad,
+        )
+
+    metrics.reset()
+    metrics.enable()
+    try:
+        assert ingest(5, 70) == (1, 1, 58)  # buckets (8, 128): compiles once
+        assert ingest(7, 100) == (0, 0, 28)  # same buckets: reused
+        assert ingest(9, 90) == (1, 1, 38)  # D crosses 8: exactly one more
+        assert ingest(16, 128) == (0, 0, 0)  # (16, 128) again, no padding
+    finally:
+        metrics.disable()
+        metrics.reset()
