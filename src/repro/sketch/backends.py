@@ -574,29 +574,63 @@ def _sparse_kernel_module():
 _SPARSE_CELLS_CROSSOVER = 32
 
 
-@partial(jax.jit, static_argnames=("rows", "m"))
-def sparse_merge_sorted(row, bucket, rank, *, rows, m):
-    """Sorted-stream dedup: two-pass stable argsort over (row, bucket) cells.
+def cell_space_fits(rows: int, m: int) -> bool:
+    """True when flattened ``row * m + bucket`` cell ids fit int32.
 
-    ONE stable sort by rank ascending, then (stably) by ``row * m + bucket``
-    cell id, so within each equal-cell run ranks ascend and the LAST element
-    carries the cell's max.  Invalid entries (padding, out-of-range rows)
-    sort to a trailing sentinel cell and never survive.  Cost tracks the
+    The TPU has no 64-bit datapath, so the layouts that form such ids (the
+    dense-cells map and the Pallas ``sparse_scatter`` kernel) serve only
+    banks below 2^31 cells; the (row, bucket) sort serves every size.
+    """
+    return rows * m < 1 << 31
+
+
+def _check_cell_space(rows: int, m: int) -> None:
+    """The guard of every layout that flattens (row, bucket) into one id:
+    past 2^31 cells the int32 ids would silently wrap."""
+    if not cell_space_fits(rows, m):
+        raise ValueError(
+            f"bank cell space B*m = {rows}*{m} overflows the int32 cell ids "
+            f"of the flattened dedup layouts (dense cells, Pallas "
+            f"sparse_scatter); the (row, bucket) sorted dedup serves banks "
+            f"of this size"
+        )
+
+
+@partial(jax.jit, static_argnames=("rows",))
+def sparse_merge_sorted(row, bucket, rank, *, rows):
+    """Sorted-stream dedup: two stable argsorts, by (bucket, rank) then row.
+
+    The first sort orders by ``bucket << 8 | rank`` (a row-local key, below
+    2^24 at p <= 16), the second, stably, by row, so the stream ends up in
+    (row, bucket, rank) order: within each equal (row, bucket) run ranks
+    ascend and the LAST element carries the max.  Invalid entries
+    (padding, out-of-range rows) take the sentinel row ``rows`` and sort
+    to the end, where they never survive.  No ``row * m + bucket`` id is
+    formed, so a bank's ``rows * m`` may pass 2^31.  Two single-key sorts,
+    not one multi-key ``lax.sort``: the latter runs faster on a v5e but
+    compiles about four times longer (PERF.md, section 6), and compaction
+    meets new stream lengths inside a serving window.  Cost tracks the
     stream, not the bank — the right trade for small compactions.
     """
     valid = (row >= 0) & (row < rows)
-    cell = jnp.where(valid, row * m + bucket, rows * m)
-    order1 = jnp.argsort(rank, stable=True)
-    cell1, rank1 = cell[order1], rank[order1]
-    order2 = jnp.argsort(cell1, stable=True)
-    cell_s, rank_s = cell1[order2], rank1[order2]
-    is_last = jnp.concatenate([cell_s[1:] != cell_s[:-1], jnp.ones((1,), bool)])
-    survivor = is_last & (cell_s < rows * m)
-    row_s = cell_s // m
+    key = (bucket << 8) | rank
+    order1 = jnp.argsort(key, stable=True)
+    row1 = jnp.where(valid, row, rows)[order1]
+    key1 = key[order1]
+    order2 = jnp.argsort(row1, stable=True)
+    row_s, key_s = row1[order2], key1[order2]
+    bucket_s, rank_s = key_s >> 8, key_s & 0xFF
+    is_last = jnp.concatenate(
+        [
+            (row_s[1:] != row_s[:-1]) | (bucket_s[1:] != bucket_s[:-1]),
+            jnp.ones((1,), bool),
+        ]
+    )
+    survivor = is_last & (row_s < rows)
     distinct = jnp.bincount(jnp.where(survivor, row_s, rows), length=rows + 1)[
         :rows
     ]
-    return cell_s, rank_s, survivor, distinct.astype(jnp.int32)
+    return row_s, bucket_s, rank_s, survivor, distinct.astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("rows", "m"))
@@ -607,7 +641,9 @@ def sparse_merge_cells(row, bucket, rank, *, rows, m):
     (rows, m) max-rank map instead of live registers; per-row distinct
     counts fall out of one popcount over the map.  Cost is O(n + rows*m)
     flat in the stream — the right trade once the stream rivals the bank.
+    Banks below 2^31 cells only (``_check_cell_space``).
     """
+    _check_cell_space(rows, m)
     valid = (row >= 0) & (row < rows)
     seg = jnp.where(valid, row * m + bucket, rows * m)
     cells = jax.ops.segment_max(
@@ -646,6 +682,7 @@ def sparse_merge(
     _sparse = _sparse_kernel_module()
     interpret = _resolve_interpret(interpret)
     m = cfg.m
+    _check_cell_space(rows, m)
     if m > _sparse.MAX_BLOCK_CELLS:
         raise ValueError(
             f"pallas sparse dedup supports m <= {_sparse.MAX_BLOCK_CELLS} "
@@ -684,14 +721,18 @@ def sparse_merge(
 def _jnp_sparse_backend(row, bucket, rank, rows, cfg: HLLConfig, plan: ExecutionPlan):
     m = cfg.m
     n = row.shape[0]
-    if n * _SPARSE_CELLS_CROSSOVER >= rows * m:
+    if cell_space_fits(rows, m) and n * _SPARSE_CELLS_CROSSOVER >= rows * m:
         cells, distinct = sparse_merge_cells(row, bucket, rank, rows=rows, m=m)
         return SparseDedup(distinct=distinct, cells=cells)
-    cell_s, rank_s, survivor, distinct = sparse_merge_sorted(
-        row, bucket, rank, rows=rows, m=m
+    row_s, bucket_s, rank_s, survivor, distinct = sparse_merge_sorted(
+        row, bucket, rank, rows=rows
     )
     return SparseDedup(
-        distinct=distinct, cell_s=cell_s, rank_s=rank_s, survivor=survivor
+        distinct=distinct,
+        row_s=row_s,
+        bucket_s=bucket_s,
+        rank_s=rank_s,
+        survivor=survivor,
     )
 
 

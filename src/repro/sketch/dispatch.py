@@ -19,6 +19,7 @@ from jax.sharding import PartitionSpec as P
 from repro.compat import shard_map
 from repro.obs import metrics as obs_metrics
 from repro.sketch import hll
+from repro.sketch.backends import cell_space_fits
 from repro.sketch.hll import HLLConfig
 from repro.sketch.plan import (
     DEFAULT_PLAN,
@@ -279,19 +280,25 @@ def dedup_pairs(
     Pallas kernel) collapses the combined live-pair + append-buffer stream
     to each row's distinct bucket -> max-rank map and per-row distinct
     counts, returned as a :class:`repro.sketch.plan.SparseDedup`.  The
-    dedup always runs on the caller's device regardless of ``placement`` —
-    compaction consumes host-resident COO state, so there is no stream to
-    shard (mesh plans shard the *ingest* phases instead).  A backend with
-    no sparse registration (e.g. a custom bank backend) falls back to the
-    jnp dedup: every sparse path is bit-identical by contract, so the
-    fallback cannot change the compacted state.
+    dedup runs on the device of its inputs regardless of ``placement`` —
+    compaction consumes host-resident COO state, and under the sharded
+    placement each row block compacts on its own device (DESIGN.md §16).
+    A bank whose ``rows * m`` reaches 2^31 ("wide") always takes the jnp
+    (row, bucket) sort, the one layout that forms no flattened cell id.
+    A backend with no sparse registration (e.g. a custom bank backend)
+    falls back to the jnp dedup: every sparse path is bit-identical by
+    contract, so the fallback cannot change the compacted state.
     """
     plan = (DEFAULT_PLAN if plan is None else plan).validate()
-    try:
-        backend = get_sparse_backend(plan.backend)
-    except ValueError:
-        obs_metrics.inc("dispatch.sparse_dedup.fallback")
+    if not cell_space_fits(rows, cfg.m):
+        obs_metrics.inc("sparse.dedup.wide")
         backend = get_sparse_backend("jnp")
+    else:
+        try:
+            backend = get_sparse_backend(plan.backend)
+        except ValueError:
+            obs_metrics.inc("dispatch.sparse_dedup.fallback")
+            backend = get_sparse_backend("jnp")
     return backend(row, bucket, rank, rows, cfg, plan)
 
 

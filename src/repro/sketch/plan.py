@@ -113,23 +113,26 @@ class SparseDedup(NamedTuple):
     COO pairs, promoted registers, and distinct counts derived from either
     are bit-identical):
 
-    * **sorted stream** (``cells=None``): ``cell_s`` holds ``row*m + bucket``
-      ids sorted ascending with padding at a trailing sentinel, ``rank_s``
-      the co-sorted ranks, and ``survivor`` marks the last (max-rank) entry
-      of each live cell run — the argsort form, cost O(n log n) in the
-      stream length, which wins when the stream is small next to the bank.
+    * **sorted stream** (``cells=None``): ``row_s`` and ``bucket_s`` hold the
+      (row, bucket) keys sorted ascending with padding at a trailing
+      sentinel row, ``rank_s`` the co-sorted ranks, and ``survivor`` marks
+      the last (max-rank) entry of each live (row, bucket) run — the sort
+      form, cost O(n log n) in the stream length, which wins when the
+      stream is small next to the bank and is the only layout past 2^31
+      cells (no flattened cell id is formed).
     * **dense cells** (``cells`` set): ``cells`` is the (rows, m) int32
       max-rank map itself (0 = untouched bucket) and the stream fields are
       None — the scatter form (jnp segment-max or the sparse_scatter Pallas
       kernel), cost O(n + rows*m), which wins once the stream rivals the
-      bank's cell count.
+      bank's cell count; banks below 2^31 cells only.
 
     ``distinct`` is always the (rows,) int32 per-row distinct-bucket count.
     """
 
     distinct: "jax.Array"
     cells: Optional["jax.Array"] = None
-    cell_s: Optional["jax.Array"] = None
+    row_s: Optional["jax.Array"] = None
+    bucket_s: Optional["jax.Array"] = None
     rank_s: Optional["jax.Array"] = None
     survivor: Optional["jax.Array"] = None
 

@@ -72,6 +72,26 @@ hybrid — while ``SketchBank.from_bytes`` keeps rejecting v2 with a
 targeted error.  Serialization always writes the compacted state: the
 append buffer is transient and never hits the wire.
 
+**Row blocks under the sharded placement (DESIGN.md §16).** Given a
+``placement="sharded"`` plan over more than one device, ``update_many``
+splits the bank into contiguous tenant-row blocks, one per device of
+``plan.data_axes`` (``block_rows = ceil(B / devices)``, the last block
+holding the rest), and from then on the bank's per-row state lives in
+``blocks``: each is a local ``HybridBank`` whose arrays sit on its own
+device, with its own append buffer, pressure compaction, promotions and
+dense block.  Each tick is split on the host by block (keys re-based by
+``block_index * block_rows``; keys outside [0, B) reach no block — the §9
+drop rule), and every per-block call runs with that block's device as
+JAX's default, so compaction, dedup and the dense scatter run beside the
+block's state.  Rows never interact, so every read (estimates, registers,
+counters, modes, the wire format) is the concatenation of the blocks'
+reads, bit-identical to the same ops under a local plan.  A bank that is
+not split (a one-device mesh, or no ingest yet) runs every phase on its
+own device under the local form of the plan.  A bank's cell
+space ``B * m`` may pass 2^31: the dedup keys on (row, bucket) with no
+flattened id (``backends.sparse_merge_sorted``), and the layouts that do
+flatten (dense cells, the Pallas kernel) are only picked below 2^31.
+
 ``HybridBank`` is host-orchestrated (promotion reshapes the dense block),
 so unlike ``SketchBank`` it is NOT a jit-traceable pytree; the fused
 device work happens inside the jitted dedup/scatter kernels behind
@@ -83,6 +103,7 @@ from __future__ import annotations
 import dataclasses
 import struct
 import threading
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 from typing import Optional, Sequence, Tuple
 
@@ -100,11 +121,10 @@ from repro.sketch.bank import (
     _ROW_COUNT,
     SketchBank,
     _counter_add_rows,
-    _sharded_estimate_fn,
     update_bank_registers,
 )
 from repro.sketch.carrier import HyperLogLog
-from repro.sketch.dispatch import dedup_pairs, row_shard_apply
+from repro.sketch.dispatch import _shard_count, dedup_pairs
 from repro.sketch.hll import HLLConfig
 from repro.sketch.plan import DEFAULT_PLAN, ExecutionPlan, SparseDedup
 
@@ -147,17 +167,6 @@ def _check_threshold(threshold: int, cfg: HLLConfig) -> int:
     return threshold
 
 
-def _check_cell_space(rows: int, m: int) -> None:
-    """The one guard for every dedup entry: flattened (row, bucket) cell
-    ids must fit int32 (TPU has no 64-bit datapath), or the dedup backends
-    would silently wrap them."""
-    if rows * m >= 1 << 31:
-        raise ValueError(
-            f"bank cell space B*m = {rows}*{m} overflows int32 sort "
-            f"cells; split the fleet across multiple banks"
-        )
-
-
 def _pending_pressure(pending, pair_len) -> bool:
     """True once the buffer passes both flush floors (module note)."""
     if pending is None or pending.total < _FLUSH_MIN_PAIRS:
@@ -170,6 +179,35 @@ def _pow2_bucket(n: int, floor: int = 6) -> int:
     """Smallest power of two >= ``n`` and >= 2^floor: the jit-shape buckets
     that bound recompiles of every padded dispatch in this module."""
     return 1 << max(floor, (n - 1).bit_length())
+
+
+def _bytes_limit(device) -> Optional[int]:
+    """The bytes ``device`` reports it can hold; None where it reports no
+    limit (a CPU device)."""
+    return (device.memory_stats() or {}).get("bytes_limit")
+
+
+def _device_of(bank: "HybridBank"):
+    """The device that holds a local bank's per-row arrays."""
+    return next(iter(bank.n_items.devices()))
+
+
+def _block_devices(plan: ExecutionPlan) -> list:
+    """Devices of a sharded plan's row blocks, in block order: row-major
+    over ``plan.data_axes`` (as ``P(axes)`` shards), first index on any
+    other mesh axis."""
+    names = plan.mesh.axis_names
+    order = [names.index(a) for a in plan.data_axes]
+    order += [i for i in range(len(names)) if i not in order]
+    devs = np.transpose(plan.mesh.devices, order)
+    return list(devs.reshape(_shard_count(plan), -1)[:, 0])
+
+
+def _local_plan(plan: Optional[ExecutionPlan]) -> Optional[ExecutionPlan]:
+    """``plan`` for one row block: same backend and estimator, local."""
+    if plan is None or plan.placement == "local":
+        return plan
+    return dataclasses.replace(plan, placement="local", mesh=None)
 
 
 # (row bucket, length bucket) pairs the dense dispatch has sent this process
@@ -216,16 +254,16 @@ def _hash_stream(items, cfg: HLLConfig):
     return hll.hash_index_rank(items, cfg)
 
 
-@partial(jax.jit, static_argnames=("rows", "m", "cap"))
-def _compact_pairs(cell_s, rank_s, survivor, keep_row, *, rows, m, cap):
+@partial(jax.jit, static_argnames=("rows", "cap"))
+def _compact_pairs(row_s, bucket_s, rank_s, survivor, keep_row, *, rows, cap):
     """Scatter surviving pairs of still-sparse rows into a (B, cap) buffer.
 
     Survivors arrive sorted by (row, bucket); each kept entry's slot is
     its running index within its row, so the output rows are bucket-sorted
     with ``-1`` padding — the invariant the v2 wire format serializes.
+    The scatter indexes (row, slot) directly, so no id of the form
+    ``row * cap + slot`` can overflow int32 on a large bank.
     """
-    row_s = cell_s // m
-    bucket_s = cell_s - row_s * m
     safe_row = jnp.clip(row_s, 0, rows - 1)
     take = survivor & keep_row[safe_row] & (row_s < rows)
     pos = jnp.cumsum(take.astype(jnp.int32)) - 1
@@ -236,11 +274,13 @@ def _compact_pairs(cell_s, rank_s, survivor, keep_row, *, rows, m, cap):
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(row_counts)[:-1].astype(jnp.int32)]
     )
     offset = pos - row_start[safe_row]
-    idx = jnp.where(take & (offset < cap), safe_row * cap + offset, rows * cap)
+    ok = take & (offset < cap)
     packed = (bucket_s << _PACK_SHIFT) | rank_s
-    out = jnp.full((rows * cap,), _EMPTY, jnp.int32)
-    out = out.at[idx].set(packed, mode="drop")
-    return out.reshape(rows, cap)
+    out = jnp.full((rows, cap), _EMPTY, jnp.int32)
+    # rows index past the buffer is dropped: that is every entry not kept
+    return out.at[jnp.where(ok, safe_row, rows), jnp.where(ok, offset, 0)].set(
+        packed, mode="drop"
+    )
 
 
 def _compact_cells(cells_np, keep_row, distinct, *, cap):
@@ -272,7 +312,9 @@ def _compact_cells(cells_np, keep_row, distinct, *, cap):
 
 
 @partial(jax.jit, static_argnames=("slots", "rows", "m"))
-def _materialize_rows(cell_s, rank_s, survivor, slot_of_row, *, slots, rows, m):
+def _materialize_rows(
+    row_s, bucket_s, rank_s, survivor, slot_of_row, *, slots, rows, m
+):
     """Scatter surviving pairs of promoted rows into fresh dense registers.
 
     ``slot_of_row`` maps each promoted row to a local slot in [0, slots);
@@ -280,17 +322,13 @@ def _materialize_rows(cell_s, rank_s, survivor, slot_of_row, *, slots, rows, m):
     the row's FULL deduped bucket -> max-rank map, so the produced
     registers are bit-identical to dense-from-scratch ingestion.
     """
-    row_s = cell_s // m
-    bucket_s = cell_s - row_s * m
     slot = slot_of_row[jnp.clip(row_s, 0, rows - 1)]
     take = survivor & (row_s < rows) & (slot >= 0)
-    seg = jnp.where(take, slot * m + bucket_s, slots * m)
-    regs = jax.ops.segment_max(
-        jnp.where(take, rank_s, 0).astype(hll.REGISTER_DTYPE),
-        seg,
-        num_segments=slots * m + 1,
+    regs = jnp.zeros((slots, m), hll.REGISTER_DTYPE)
+    # slot ``slots`` lies past the block: entries not taken are dropped
+    return regs.at[jnp.where(take, slot, slots), jnp.where(take, bucket_s, 0)].max(
+        rank_s.astype(hll.REGISTER_DTYPE), mode="drop"
     )
-    return regs[: slots * m].reshape(slots, m)
 
 
 def _dedup_products(
@@ -325,17 +363,18 @@ def _dedup_products(
         )
     else:
         pairs = _compact_pairs(
-            dd.cell_s,
+            dd.row_s,
+            dd.bucket_s,
             dd.rank_s,
             dd.survivor,
             jnp.asarray(keep),
             rows=rows,
-            m=m,
             cap=cap,
         )
         dense = (
             _materialize_rows(
-                dd.cell_s,
+                dd.row_s,
+                dd.bucket_s,
                 dd.rank_s,
                 dd.survivor,
                 jnp.asarray(slot_of_row),
@@ -401,17 +440,20 @@ class HybridBank:
     buffer; external readers should use the ``pairs`` / ``sparse_len`` /
     ``dense`` / ``dense_slot`` properties (or any read method), which
     compact the buffer first — raw fields are only safe on a bank whose
-    ``pending`` is None.
+    ``pending`` is None.  A row-blocked bank (sharded placement, module
+    note) keeps its rows in ``blocks`` and its own array fields are None.
     """
 
-    pair_buf: jnp.ndarray  # (B, C) int32 packed bucket<<8|rank, -1 = empty
-    pair_len: jnp.ndarray  # (B,) int32 distinct buckets (0 for dense rows)
-    dense_block: jnp.ndarray  # (D, m) uint8 registers of promoted rows
-    slot_map: jnp.ndarray  # (B,) int32 slot into dense_block, -1 = sparse
-    n_items: jnp.ndarray  # (B, 2) uint32 limb pairs, exact per-row counts
+    pair_buf: Optional[jnp.ndarray]  # (B, C) int32 bucket<<8|rank, -1 = empty
+    pair_len: Optional[jnp.ndarray]  # (B,) int32 distinct buckets (0 if dense)
+    dense_block: Optional[jnp.ndarray]  # (D, m) uint8 registers of promoted rows
+    slot_map: Optional[jnp.ndarray]  # (B,) int32 slot into dense_block, -1 = sparse
+    n_items: Optional[jnp.ndarray]  # (B, 2) uint32 limb pairs, exact counts
     cfg: HLLConfig
     threshold: int  # promote when a row's distinct buckets exceed this
     pending: Optional[_PendingLog] = None  # un-deduplicated append buffer
+    # contiguous row blocks, one local bank per device (sharded placement)
+    blocks: Tuple["HybridBank", ...] = ()
 
     # ------------------------------------------------------------------
     # construction
@@ -499,12 +541,95 @@ class HybridBank:
         return cls.from_dense(SketchBank.from_sketches(sketches), threshold)
 
     # ------------------------------------------------------------------
+    # row blocks (sharded placement; module note)
+    # ------------------------------------------------------------------
+
+    def _split(self, devices) -> "HybridBank":
+        """This local bank as contiguous row blocks, block d on
+        ``devices[d]``; blocks are ``ceil(B / len(devices))`` rows, the
+        last one the rest.  Dense slots renumber in row order per block."""
+        s = self.compact()
+        rows = len(s)
+        block_rows = -(-rows // len(devices))
+        pairs = to_host(s.pair_buf)
+        pair_len = to_host(s.pair_len)
+        slots = to_host(s.slot_map)
+        limbs = to_host(s.n_items)
+        dense = to_host(s.dense_block)
+        blocks = []
+        for dev, lo in zip(devices, range(0, rows, block_rows)):
+            hi = min(rows, lo + block_rows)
+            slot = slots[lo:hi]
+            mine = slot >= 0
+            local_slot = np.full(hi - lo, -1, np.int32)
+            local_slot[mine] = np.arange(int(mine.sum()), dtype=np.int32)
+            # pair rows fill from slot 0, so the block's own capacity suffices
+            cap = _fit_capacity(int(pair_len[lo:hi].max(initial=0)), s.threshold)
+            put = partial(jax.device_put, device=dev)
+            blocks.append(
+                HybridBank(
+                    put(pairs[lo:hi, :cap]),
+                    put(pair_len[lo:hi]),
+                    put(dense[slot[mine]]),
+                    put(local_slot),
+                    put(limbs[lo:hi]),
+                    s.cfg,
+                    s.threshold,
+                )
+            )
+        return HybridBank(
+            None, None, None, None, None, s.cfg, s.threshold, blocks=tuple(blocks)
+        )
+
+    def _blocked_like(self, like: "HybridBank") -> "HybridBank":
+        """This bank in ``like``'s row blocks (splitting a local bank)."""
+        devices = [_device_of(b) for b in like.blocks]
+        if not self.blocks:
+            return self._split(devices)
+        if [len(b) for b in self.blocks] != [len(b) for b in like.blocks] or [
+            _device_of(b) for b in self.blocks
+        ] != devices:
+            raise ValueError("cannot combine banks split into different row blocks")
+        return self
+
+    def _per_block(self, fn, *per_block_args) -> list:
+        """``fn(block_d, *args_d)`` for every row block d, results in block
+        order.  The blocks run concurrently, one host thread each with its
+        block's device as JAX's default device, so one block's compiles,
+        transfers and host scans overlap the others' (jit compiles once
+        per device; the registry and the dense-shape set are locked)."""
+
+        def run(d):
+            block = self.blocks[d]
+            with jax.default_device(_device_of(block)):
+                return fn(block, *(a[d] for a in per_block_args))
+
+        with ThreadPoolExecutor(len(self.blocks)) as pool:
+            return list(pool.map(run, range(len(self.blocks))))
+
+    def _with_blocks(self, blocks) -> "HybridBank":
+        return dataclasses.replace(self, blocks=tuple(blocks))
+
+    def _local_view(self) -> "HybridBank":
+        """The settled state of a local bank; a row-blocked bank has no
+        single (B, C) pair buffer or (D, m) block to hand out."""
+        if self.blocks:
+            raise ValueError(
+                "a row-blocked bank (sharded placement) keeps no whole-bank "
+                "pair buffer or dense block; read it through row_registers, "
+                "row, counts, modes, density or estimate_many"
+            )
+        return self.compact()
+
+    # ------------------------------------------------------------------
     # compaction (the append buffer's one exit; every read routes here)
     # ------------------------------------------------------------------
 
     @property
     def pending_pairs(self) -> int:
         """Raw (bucket, rank) appends buffered since the last compaction."""
+        if self.blocks:
+            return sum(b.pending_pairs for b in self.blocks)
         return 0 if self.pending is None else self.pending.total
 
     def compact(self, _reason: str = "read") -> "HybridBank":
@@ -518,8 +643,11 @@ class HybridBank:
 
         ``_reason`` labels the flush for the metrics registry: "read" for
         settle-reads (a read surface forcing the buffer down), "pressure"
-        when the ingest path crossed the flush floors.
+        when the ingest path crossed the flush floors.  A row-blocked
+        bank compacts block by block, each on its own device.
         """
+        if self.blocks:
+            return self._with_blocks(self._per_block(lambda b: b.compact(_reason)))
         if self.pending is None:
             return self
         cached = self.__dict__.get("_settled")
@@ -599,41 +727,49 @@ class HybridBank:
     # ------------------------------------------------------------------
 
     def __len__(self) -> int:
+        if self.blocks:
+            return sum(len(b) for b in self.blocks)
         return int(self.n_items.shape[0])
 
     @property
     def pairs(self) -> jnp.ndarray:
         """(B, C) packed pair buffer of the settled state."""
-        return self.compact().pair_buf
+        return self._local_view().pair_buf
 
     @property
     def sparse_len(self) -> jnp.ndarray:
         """(B,) int32 distinct-bucket counts of the settled state."""
-        return self.compact().pair_len
+        return self._local_view().pair_len
 
     @property
     def dense(self) -> jnp.ndarray:
         """(D, m) uint8 dense block of the settled state."""
-        return self.compact().dense_block
+        return self._local_view().dense_block
 
     @property
     def dense_slot(self) -> jnp.ndarray:
         """(B,) int32 row -> dense slot map of the settled state."""
-        return self.compact().slot_map
+        return self._local_view().slot_map
 
     @property
     def capacity(self) -> int:
-        """Current per-row sparse pair capacity C."""
+        """Current per-row sparse pair capacity C (the widest block's)."""
+        if self.blocks:
+            return max(b.capacity for b in self.compact().blocks)
         return int(self.compact().pair_buf.shape[1])
 
     @property
     def dense_rows(self) -> int:
         """Number of promoted rows (the D of the dense block)."""
+        if self.blocks:
+            return sum(b.dense_rows for b in self.compact().blocks)
         return int(self.compact().dense_block.shape[0])
 
     @property
     def modes(self) -> np.ndarray:
         """(B,) uint8 row modes: MODE_SPARSE (0) or MODE_DENSE (1)."""
+        if self.blocks:
+            return np.concatenate([b.modes for b in self.compact().blocks])
         return (to_host(self.compact().slot_map) >= 0).astype(np.uint8)
 
     @property
@@ -643,6 +779,8 @@ class HybridBank:
         Counters update eagerly at ingest (one bincount per batch), so
         they never wait on a compaction.
         """
+        if self.blocks:
+            return np.concatenate([b.counts for b in self.blocks])
         limbs = to_host(self.n_items)
         hi = limbs[:, 0].astype(np.uint64)
         lo = limbs[:, 1].astype(np.uint64)
@@ -651,6 +789,8 @@ class HybridBank:
     @property
     def nbytes(self) -> int:
         """Storage footprint of the settled hybrid representation."""
+        if self.blocks:
+            return sum(b.nbytes for b in self.compact().blocks)
         s = self.compact()
         return int(
             s.pair_buf.nbytes
@@ -662,9 +802,26 @@ class HybridBank:
 
     def density(self) -> dict:
         """Storage introspection: modes, occupancy, and the memory win."""
+        rows = len(self)
+        m = self.cfg.m
+        dense_nbytes = rows * m + rows * 8  # what a SketchBank would cost
+        if self.blocks:
+            per = [b.density() for b in self.compact().blocks]
+            nbytes = sum(d["nbytes"] for d in per)
+            dense_rows = sum(d["dense_rows"] for d in per)
+            return {
+                "rows": rows,
+                "dense_rows": dense_rows,
+                "sparse_rows": rows - dense_rows,
+                "capacity": max(d["capacity"] for d in per),
+                "threshold": self.threshold,
+                "occupancy_mean": sum(d["occupancy_mean"] * d["rows"] for d in per)
+                / rows,
+                "nbytes": nbytes,
+                "dense_nbytes": dense_nbytes,
+                "reduction": dense_nbytes / nbytes if nbytes else 0.0,
+            }
         s = self.compact()
-        rows = len(s)
-        m = s.cfg.m
         d = int(s.dense_block.shape[0])
         occ = to_host(s.pair_len).astype(np.int64)
         if d:
@@ -672,7 +829,6 @@ class HybridBank:
             slot_np = to_host(s.slot_map)
             occ = occ + np.zeros_like(occ)
             occ[slot_np >= 0] = dense_occ[slot_np[slot_np >= 0]]
-        dense_nbytes = rows * m + rows * 8  # what a SketchBank would cost
         return {
             "rows": rows,
             "dense_rows": d,
@@ -691,6 +847,11 @@ class HybridBank:
         if not -rows <= i < rows:
             raise IndexError(f"row {i} out of range for a {rows}-row bank")
         i = i % rows
+        if self.blocks:
+            block_rows = len(self.blocks[0])
+            block = self.blocks[i // block_rows]
+            with jax.default_device(_device_of(block)):
+                return block.row(i % block_rows)
         s = self.compact()
         slot = int(s.slot_map[i])
         if slot >= 0:
@@ -702,6 +863,43 @@ class HybridBank:
             regs_np[p >> _PACK_SHIFT] = (p & _PACK_MASK).astype(np.uint8)
             regs = jnp.asarray(regs_np)
         return HyperLogLog(regs, s.n_items[i], s.cfg)
+
+    def row_registers(self, start: int, stop: int) -> np.ndarray:
+        """(stop - start, m) uint8 registers of rows [start, stop), on host.
+
+        Reads only those rows (of the blocks that hold them), so a bank too
+        large to materialize whole — on one device or on the host — can be
+        read and checked range by range.
+        """
+        rows = len(self)
+        if not 0 <= start <= stop <= rows:
+            raise IndexError(
+                f"rows [{start}, {stop}) out of range for a {rows}-row bank"
+            )
+        m = self.cfg.m
+        if self.blocks:
+            block_rows = len(self.blocks[0])
+            parts = []
+            for d, block in enumerate(self.blocks):
+                base = d * block_rows
+                lo, hi = max(start, base), min(stop, base + len(block))
+                if lo < hi:
+                    with jax.default_device(_device_of(block)):
+                        parts.append(block.row_registers(lo - base, hi - base))
+            return np.concatenate(parts) if parts else np.zeros((0, m), np.uint8)
+        s = self.compact()
+        n = stop - start
+        regs = np.zeros((n, m), np.uint8)
+        # a traced start keeps one slice executable per range length
+        pairs = to_host(jax.lax.dynamic_slice_in_dim(s.pair_buf, start, n))
+        r, c = np.nonzero(pairs >= 0)
+        p = pairs[r, c]
+        regs[r, p >> _PACK_SHIFT] = (p & _PACK_MASK).astype(np.uint8)
+        slot = to_host(jax.lax.dynamic_slice_in_dim(s.slot_map, start, n))
+        dense = np.nonzero(slot >= 0)[0]
+        if dense.size:
+            regs[dense] = to_host(s.dense_block[jnp.asarray(slot[dense])])
+        return regs
 
     # ------------------------------------------------------------------
     # conversion
@@ -745,7 +943,26 @@ class HybridBank:
         return regs
 
     def to_dense(self) -> SketchBank:
-        """Materialize to a plain dense ``SketchBank`` (lossless)."""
+        """Materialize to a plain dense ``SketchBank`` (lossless).
+
+        A row-blocked bank gathers its (B, m) registers on its first
+        block's device, and refuses where they pass the bytes that device
+        reports it can hold; read such a bank in ranges with
+        ``row_registers``.
+        """
+        if self.blocks:
+            rows, m = len(self), self.cfg.m
+            device = _device_of(self.blocks[0])
+            limit = _bytes_limit(device)
+            if limit is not None and rows * m > limit:
+                raise ValueError(
+                    f"to_dense of a {rows}-row bank at m={m} needs {rows * m} "
+                    f"B, more than the {limit} B its device holds; read it in "
+                    f"row ranges with row_registers"
+                )
+            limbs = np.concatenate([to_host(b.n_items) for b in self.blocks])
+            put = partial(jax.device_put, device=device)
+            return SketchBank(put(self.row_registers(0, rows)), put(limbs), self.cfg)
         return SketchBank(self._dense_registers(), self.n_items, self.cfg)
 
     def to_sketches(self) -> list:
@@ -773,8 +990,20 @@ class HybridBank:
         change the outcome: the register lattice is a max, so the settled
         state is bit-identical to eager per-batch dedup.  Zero-length
         streams and zero-row banks return ``self`` without dispatching
-        any backend.
+        any backend.  A sharded ``plan`` over more than one device splits
+        a local bank into row blocks first; a row-blocked bank splits the
+        stream by block and ingests each part on its block's device under
+        the local form of ``plan`` (module note).
         """
+        if self.blocks:
+            return self._update_blocks(keys, items, plan)
+        if (
+            plan is not None
+            and plan.placement == "sharded"
+            and len(self)
+            and _shard_count(plan.validate()) > 1
+        ):
+            return self._split(_block_devices(plan))._update_blocks(keys, items, plan)
         with span("sparse.route"):
             keys_np = to_host(keys).reshape(-1)
             items_np = to_host(items).reshape(-1)
@@ -786,8 +1015,8 @@ class HybridBank:
             rows = len(self)
             if items_np.shape[0] == 0 or rows == 0:
                 return self
-            _check_cell_space(rows, self.cfg.m)
-            plan = (DEFAULT_PLAN if plan is None else plan).validate()
+            # an unsplit bank (one device) runs every phase locally
+            plan = _local_plan((DEFAULT_PLAN if plan is None else plan).validate())
             keys_np = keys_np.astype(np.int32, copy=False)
             slot_np = to_host(self.slot_map)
             valid = (keys_np >= 0) & (keys_np < rows)
@@ -826,6 +1055,33 @@ class HybridBank:
             return out.compact(_reason="pressure")
         return out
 
+    def _update_blocks(self, keys, items, plan) -> "HybridBank":
+        """Split a tick on the host by row block and ingest each part on
+        its block's device: ``key - block_index * block_rows``."""
+        with span("sparse.shard.split"):
+            keys_np = to_host(keys).reshape(-1)
+            items_np = to_host(items).reshape(-1)
+            if keys_np.shape[0] != items_np.shape[0]:
+                raise ValueError(
+                    f"keys ({keys_np.shape[0]}) and items "
+                    f"({items_np.shape[0]}) must flatten to the same length"
+                )
+            keys_np = keys_np.astype(np.int32, copy=False)
+            block_rows = len(self.blocks[0])
+            # a key outside [0, B) reaches no block: the §9 drop rule
+            block = np.where(
+                (keys_np >= 0) & (keys_np < len(self)), keys_np // block_rows, -1
+            )
+            parts = []
+            for d in range(len(self.blocks)):
+                sel = block == d
+                obs_metrics.inc(f"sparse.shard.pairs.{d}", int(sel.sum()))
+                parts.append((keys_np[sel] - d * block_rows, items_np[sel]))
+        local = _local_plan(plan)
+        return self._with_blocks(
+            self._per_block(lambda b, kx: b.update_many(kx[0], kx[1], local), parts)
+        )
+
     def _dense_update(self, slots, items, plan: ExecutionPlan) -> jnp.ndarray:
         """Scatter a dense-destined (slot, item) sub-stream at bucketed shapes.
 
@@ -833,8 +1089,7 @@ class HybridBank:
         length and the block's D change from tick to tick, so the stream
         is padded on the host to the next power of two (floor 2^6) with
         slot -1 / item 0, and the block to the next power of two of D with
-        zero rows.  Slot -1 is dropped by every backend's §9 rule (the
-        sharded placement re-bases it to a still-negative key), and no
+        zero rows.  Slot -1 is dropped by every backend's §9 rule, and no
         slot points past D, so the padding lands nothing; the result is
         sliced back to (D, m) and the stored block keeps its exact shape.
         """
@@ -871,7 +1126,8 @@ class HybridBank:
         or a sparse union crossing the threshold) materialize registers
         overlaid with each side's dense blocks, so cost tracks live pairs
         + promoted rows — which is what lets
-        ``HybridWindowedBank.fold_window`` stay sparse-sized.
+        ``HybridWindowedBank.fold_window`` stay sparse-sized.  Row-blocked
+        banks merge block by block (a local side is split to match).
         """
         if self.cfg != other.cfg:
             raise ValueError(
@@ -888,6 +1144,13 @@ class HybridBank:
                 f"cannot merge banks with different sparse thresholds: "
                 f"{self.threshold} vs {other.threshold}"
             )
+        if self.blocks or other.blocks:
+            like = self if self.blocks else other
+            a, b = self._blocked_like(like), other._blocked_like(like)
+            local = _local_plan(plan)
+            return like._with_blocks(
+                a._per_block(lambda x, y: x.merge(y, local), b.blocks)
+            )
         a, b = self.compact(), other.compact()
         rows = len(a)
         m = a.cfg.m
@@ -898,8 +1161,7 @@ class HybridBank:
         n_items = jnp.stack([limbs.hi, limbs.lo], axis=-1)
         if rows == 0:
             return dataclasses.replace(a, n_items=n_items)
-        _check_cell_space(rows, m)
-        plan = (DEFAULT_PLAN if plan is None else plan).validate()
+        plan = _local_plan((DEFAULT_PLAN if plan is None else plan).validate())
         slot_a = to_host(a.slot_map)
         slot_b = to_host(b.slot_map)
         force_dense = (slot_a >= 0) | (slot_b >= 0)
@@ -988,10 +1250,10 @@ class HybridBank:
         device path — see the module docstring proof); other estimators
         (or ``lc_fast=False``) build histograms from the pairs and run
         the registered device finalizer.  Dense rows always finalize
-        through the §8 batched ``estimate_many`` — per promoted-row block
-        under a placement="sharded" ``plan`` (§16); the sparse side is
-        host/COO math with no row axis on device, so placement cannot
-        move it.
+        through the §8 batched ``estimate_many``.  A row-blocked bank
+        finalizes block by block, each on its own device, and returns the
+        concatenation; an unsplit bank finalizes on its own device under
+        any ``plan``.
         """
         from repro.sketch import estimators as _estimators
 
@@ -999,6 +1261,17 @@ class HybridBank:
         rows = len(s)
         if rows == 0:
             return jnp.zeros((0,), jnp.float32)
+        if s.blocks:
+            local = _local_plan(plan)
+            return jnp.asarray(
+                np.concatenate(
+                    s._per_block(
+                        lambda b: to_host(
+                            b.estimate_many(estimator, lc_fast=lc_fast, plan=local)
+                        )
+                    )
+                )
+            )
         name = _estimators.resolve_estimator(
             estimator or (plan.estimator if plan is not None else None)
         )
@@ -1009,17 +1282,7 @@ class HybridBank:
             sparse_est = _finalize_histograms(hist, s.cfg, name)
         d = int(s.dense_block.shape[0])
         if d:
-            if plan is not None and plan.validate().placement == "sharded":
-                dense_est = row_shard_apply(
-                    plan,
-                    _sharded_estimate_fn(s.cfg, name),
-                    (s.dense_block,),
-                    (0,),
-                )
-            else:
-                dense_est = _estimators.estimate_many(
-                    s.dense_block, s.cfg, estimator=name
-                )
+            dense_est = _estimators.estimate_many(s.dense_block, s.cfg, estimator=name)
             slot = jnp.clip(s.slot_map, 0, d - 1)
             return jnp.where(s.slot_map >= 0, dense_est[slot], sparse_est)
         return sparse_est
@@ -1040,7 +1303,6 @@ class HybridBank:
         transient append log.
         """
         s = self.compact()
-        rows = len(s)
         header = _BANK_HEADER.pack(
             _BANK_MAGIC,
             _SPARSE_VERSION,
@@ -1048,17 +1310,23 @@ class HybridBank:
             s.cfg.hash_bits,
             0,
             s.cfg.seed,
-            rows,
+            len(s),
         )
         out = [header, _THRESHOLD.pack(s.threshold)]
         out.append(s.counts.astype("<u8").tobytes())
-        modes = (to_host(s.slot_map) >= 0).astype(np.uint8)
-        out.append(modes.tobytes())
-        pairs_np = to_host(s.pair_buf)
-        dense_np = to_host(s.dense_block, dtype=np.uint8)
-        slot_np = to_host(s.slot_map)
-        for i in range(rows):
-            if modes[i] == MODE_DENSE:
+        out.append(s.modes.tobytes())
+        for part in s.blocks or (s,):
+            out.extend(part._row_payloads())
+        return b"".join(out)
+
+    def _row_payloads(self) -> list:
+        """Per-row v2 payloads of a settled local bank, in row order."""
+        pairs_np = to_host(self.pair_buf)
+        dense_np = to_host(self.dense_block, dtype=np.uint8)
+        slot_np = to_host(self.slot_map)
+        out = []
+        for i in range(len(self)):
+            if slot_np[i] >= 0:
                 out.append(dense_np[slot_np[i]].tobytes())
             else:
                 p = pairs_np[i]
@@ -1070,7 +1338,7 @@ class HybridBank:
                 pair_bytes[:, :2] = buckets.view(np.uint8).reshape(-1, 2)
                 pair_bytes[:, 2] = ranks
                 out.append(pair_bytes.tobytes())
-        return b"".join(out)
+        return out
 
     @classmethod
     def from_bytes(cls, data: bytes) -> "HybridBank":

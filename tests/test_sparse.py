@@ -22,6 +22,7 @@ from repro.sketch import (
     ExecutionPlan,
     HLLConfig,
     HybridBank,
+    HyperLogLog,
     HybridWindowedBank,
     SketchBank,
     WindowedBank,
@@ -658,16 +659,32 @@ def test_dense_destined_items_do_not_buffer():
 
 
 def test_cell_space_guard_shares_one_message():
-    big = HybridBank.empty(1 << 23, CFG)  # 2^23 * 256 = 2^31 sort cells
-    keys = jnp.zeros(4, jnp.int32)
+    """Past 2^31 cells the two layouts that flatten (row, bucket) into one
+    int32 id refuse with one shared message, and the bank itself no longer
+    refuses: it ingests and merges through the (row, bucket) sort."""
+    from repro.sketch.backends import sparse_merge, sparse_merge_cells
+
+    rows = 1 << 23  # 2^23 * 256 = 2^31 cells
+    row = jnp.zeros(4, jnp.int32)
+    bucket = jnp.arange(4, dtype=jnp.int32)
+    rank = jnp.ones(4, jnp.int32)
+    msg = r"bank cell space B\*m = 8388608\*256 overflows the int32 cell ids"
+    with pytest.raises(ValueError, match=msg) as via_cells:
+        sparse_merge_cells(row, bucket, rank, rows=rows, m=CFG.m)
+    with pytest.raises(ValueError, match=msg) as via_kernel:
+        sparse_merge(row, bucket, rank, rows, CFG)
+    # one shared guard: both flattened layouts raise the identical message
+    assert str(via_cells.value) == str(via_kernel.value)
+
+    big = HybridBank.empty(rows, CFG)
+    keys = jnp.asarray([rows - 1, rows - 1, 3, rows], jnp.int32)
     items = jnp.arange(4, dtype=jnp.int32)
-    msg = r"bank cell space B\*m = 8388608\*256 overflows int32 sort cells"
-    with pytest.raises(ValueError, match=msg) as via_update:
-        big.update_many(keys, items)
-    with pytest.raises(ValueError, match=msg) as via_merge:
-        big.merge(big)
-    # one shared guard: update_many and merge raise the identical message
-    assert str(via_update.value) == str(via_merge.value)
+    merged = big.update_many(keys, items).merge(big)
+    want = HyperLogLog.empty(CFG).update(items[:2])
+    np.testing.assert_array_equal(
+        np.asarray(merged.row(rows - 1).registers), np.asarray(want.registers)
+    )
+    assert merged.counts[rows - 1] == 2 and merged.counts.sum() == 3
 
 
 def test_sparse_backend_registry_and_fallback():
