@@ -8,7 +8,9 @@ max-lattice laws (associative, commutative, idempotent — DESIGN.md §6).
 
 The item counter is carried as two uint32 limbs (TPU has no int64 datapath;
 int32 overflows at 2.1e9 items, far below the paper's high-cardinality
-regime), giving an exact count to 2^64 items.
+regime), giving an exact count to 2^64 items.  The 64-bit add is one jitted
+dispatch whose addend is a traced (2,) uint32 limb array built on the host,
+so it compiles once for every batch size and every merge.
 
 ``to_bytes``/``from_bytes`` is the dense wire format (DESIGN.md §7): a 24-byte
 header + the raw registers, so a p=16 sketch checkpoints in 64 KiB and merges
@@ -40,14 +42,26 @@ def _counter_zero() -> jnp.ndarray:
     return jnp.zeros((2,), jnp.uint32)
 
 
+@jax.jit
+def _limb_add(counter: jnp.ndarray, addend: jnp.ndarray) -> jnp.ndarray:
+    """(hi, lo) + (hi, lo) uint32 limbs modulo 2^64, as one dispatch."""
+    s = u64lib.add(
+        u64lib.U64(counter[0], counter[1]), u64lib.U64(addend[0], addend[1])
+    )
+    return jnp.stack([s.hi, s.lo])
+
+
 def _counter_add(counter: jnp.ndarray, value) -> jnp.ndarray:
-    """64-bit add on the (hi, lo) uint32 limb pair; value is int or limbs."""
+    """64-bit add on the (hi, lo) uint32 limb pair; value is int or limbs.
+
+    An int becomes a (2,) uint32 limb array on the host, never a static or
+    weakly typed argument, so every batch size and every merge runs the one
+    compiled ``_limb_add``.
+    """
     if isinstance(value, (int, np.integer)):
         b = u64lib.from_py(int(value))
-    else:
-        b = u64lib.U64(value[0], value[1])
-    s = u64lib.add(u64lib.U64(counter[0], counter[1]), b)
-    return jnp.stack([s.hi, s.lo])
+        value = np.array([b.hi, b.lo], np.uint32)
+    return _limb_add(counter, value)
 
 
 @jax.tree_util.register_dataclass

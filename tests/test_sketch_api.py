@@ -27,7 +27,7 @@ from repro.sketch import (
     reference_plan,
     update_registers,
 )
-from repro.sketch.carrier import _counter_add
+from repro.sketch.carrier import _counter_add, _limb_add
 
 CFG = HLLConfig(p=10, hash_bits=64)  # p <= 12 so every backend is eligible
 
@@ -132,6 +132,70 @@ def test_counter_is_overflow_safe_past_int32():
     # and limb arithmetic keeps carrying well past any int32/uint32 range
     big = _counter_add(sk.n_items, (200 * 10**9))
     assert (int(big[0]) << 32 | int(big[1])) == 2**32 + 2 + 200 * 10**9
+
+
+def _limbs(value):
+    return jnp.asarray(np.array([value >> 32, value & 0xFFFFFFFF], np.uint32))
+
+
+def _with_count(value):
+    return HyperLogLog(hll.init_registers(CFG), _limbs(value), CFG)
+
+
+@pytest.mark.parametrize(
+    "start,addend,via",
+    [
+        (2**32 - 1, 3, "update"),  # carry across the 2^32 boundary
+        (2**32 - 2, 5, "update_in_jit"),  # nested jit inlines; size static
+        (2**32 - 2, 2**32 + 7, "merge"),  # limb addend: carry and hi limb
+        (2**33 - 1, 2**32 + 1, "merge_in_jit"),
+        (2**64 - 1, 1, "merge"),  # wraps modulo 2^64
+        (7, 2**40 + 3, "int"),  # int addend >= 2^32, no int32 weak type
+        (2**32 - 1, 2**33 + 1, "int"),
+        (2**64 - 2, 5, "int"),
+    ],
+)
+def test_counter_add_is_exact_modulo_2_64(start, addend, via):
+    sk = _with_count(start)
+    if via == "update":
+        got = sk.update(_items(addend)).n_items
+    elif via == "update_in_jit":
+        got = jax.jit(lambda s, x: s.update(x))(sk, _items(addend)).n_items
+    elif via == "merge":
+        got = sk.merge(_with_count(addend)).n_items
+    elif via == "merge_in_jit":
+        got = jax.jit(lambda a, b: a | b)(sk, _with_count(addend)).n_items
+    else:
+        got = _counter_add(sk.n_items, addend)
+    limbs = np.asarray(got)
+    assert limbs.dtype == np.uint32 and limbs.shape == (2,)
+    assert (int(limbs[0]) << 32 | int(limbs[1])) == (start + addend) % 2**64
+
+
+def test_counter_add_compiles_once_for_every_batch_size():
+    """The addend is traced: batch sizes 1, 3 and 2^14 and a merge share
+    one compiled ``_limb_add``, and the counter compiles nothing else."""
+    batches = [_items(n, seed=n) for n in (1, 3, 2**14)]
+    for x in batches:  # compile the register update and merge at each size
+        warm = HyperLogLog.empty(CFG).update(x)
+        warm = warm | warm
+    _limb_add.clear_cache()
+    compiled = []
+
+    def on_event(name, seconds, fun_name=None, **kw):
+        if "backend_compile" in name:
+            compiled.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        sk = HyperLogLog.empty(CFG)
+        for x in batches:
+            sk = sk.update(x)
+        sk = sk | sk
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert sk.count == 2 * (1 + 3 + 2**14)
+    assert compiled == ["jit(_limb_add)"]
 
 
 def test_merge_checks_config_and_adds_counters():
